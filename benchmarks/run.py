@@ -7,6 +7,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (analytic_scale, communicator_mttr,
                    convergence_consistency, failslow, kernel_ref,
                    lse_breakdown, migration_mttr, moe_case, proactive_mttr,

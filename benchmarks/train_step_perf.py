@@ -109,7 +109,7 @@ def bench_pallas_step(reps: int = 2, steps: int = 2) -> dict:
     jnp, plus the observed loss divergence after the first step.  Fewer reps
     than the fast/legacy comparison: interpret-mode kernels are slow and this
     row is trajectory data, not a speedup claim."""
-    import os
+    from repro.kernels.ops import interpret_mode
     cls = {up: _mk(True, use_pallas=up) for up in (False, True)}
     loss = {up: float(cl.train_step()) for up, cl in cls.items()}  # + compile
     best = {False: float("inf"), True: float("inf")}
@@ -119,7 +119,7 @@ def bench_pallas_step(reps: int = 2, steps: int = 2) -> dict:
             cls[up].run(steps)
             best[up] = min(best[up], (time.perf_counter() - t0) / steps)
     return {"jnp_ms": best[False] * 1e3, "pallas_ms": best[True] * 1e3,
-            "interpret": os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0",
+            "interpret": interpret_mode(),
             "loss_abs_diff": abs(loss[True] - loss[False])}
 
 

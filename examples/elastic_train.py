@@ -17,6 +17,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.cluster import VirtualCluster
 from repro.models.config import ModelConfig
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--global-batch", type=int, default=16)
     ap.add_argument("--report-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ModelConfig(name="elastic-demo", family="dense",
                       num_layers=args.layers, d_model=args.dmodel,

@@ -8,11 +8,12 @@ invariants.  This module turns each one from prose into an
 and every policy decision:
 
 1. **Parameter consistency** — :class:`ParameterConsistencyChecker` drives a
-   bit-exact twin cluster on the opposite code path (``fast_path=False`` =
-   the preserved ``core/legacy.py`` seed implementation) through the
-   identical event/step sequence and asserts shard-for-shard equality, and
-   independently re-derives every rank's shard from the stage's reassembled
-   master vector through the pure-Python ``zero.Layout`` ownership map.
+   twin cluster on the opposite code path (``fast_path=False`` = the
+   preserved ``core/legacy.py`` seed implementation) through the identical
+   event/step sequence and holds losses and state to a declared tolerance,
+   and independently re-derives every rank's shard from the stage's
+   reassembled master vector through the pure-Python ``zero.Layout``
+   ownership map (exact).
 2. **Dataflow consistency (§4.1)** — :class:`DataflowConsistencyChecker`:
    the global batch size is preserved exactly across every dataflow resize
    (``sum(mbs) * num_micro == global_batch``), per-rank gradient weights sum
@@ -91,21 +92,36 @@ class InvariantChecker:
 
 
 # ---------------------------------------------------------------------------
-# 1. parameter consistency (fast path == legacy oracle, shards == zero.Layout)
+# 1. parameter consistency (a lockstep twin cluster, declared tolerances)
 # ---------------------------------------------------------------------------
-class ParameterConsistencyChecker(InvariantChecker):
-    """Twin-oracle lockstep: a second cluster on the opposite code path
-    receives the identical event/step sequence; state must stay bit-identical
-    (float ``==``, no tolerance) after every event and every step."""
+class _TwinChecker(InvariantChecker):
+    """Lockstep twin: a second cluster, built by :meth:`make_twin`, receives
+    the identical event/step sequence.  Structure (layer assignment,
+    dataflow shape, stage entries/sizes/dp_ranks) and the control-plane
+    recovery-record fields must stay EXACT; losses and the master/mu/nu
+    state vectors are compared under the subclass's declared tolerance.
 
-    name = "parameter-consistency"
+    The parameter bound grows with the optimizer step count: one Adam step
+    moves an element by at most ~lr (the normalized update is bounded), so
+    two runs whose gradients differ in round-off can drift apart by at most
+    ``2*lr`` per step — a sign flip of the update of an element whose
+    gradient is ~0.  Hence ``atol = PARAM_ATOL0 + 2*lr*opt_step``.
+    """
+
+    LOSS_RTOL = 0.0
+    LOSS_ATOL = 0.0
+    PARAM_RTOL = 0.0
+    PARAM_ATOL0 = 1e-5
+    axis = "twin"           # what differs between the two clusters
 
     def __init__(self):
         self.twin = None
 
+    def make_twin(self, runner, cluster):
+        raise NotImplementedError
+
     def on_cluster_start(self, runner, cluster):
-        self.twin = runner.workload.make_cluster(
-            fast_path=not cluster.fast_path)
+        self.twin = self.make_twin(runner, cluster)
         self._compare_state("start", cluster)
 
     def after_cluster_event(self, step, event, cluster, record):
@@ -113,16 +129,20 @@ class ParameterConsistencyChecker(InvariantChecker):
         for k in ("detect", "communicator", "rng_moves"):
             if twin_rec.get(k) != record.get(k):
                 self.fail(f"step {step} {event.describe()}: recovery record "
-                          f"field {k!r} diverged (fast={record.get(k)!r}, "
-                          f"legacy={twin_rec.get(k)!r})")
+                          f"field {k!r} diverged across {self.axis} "
+                          f"({record.get(k)!r} vs {twin_rec.get(k)!r})")
         self._compare_state(f"step {step} after {event.describe()}", cluster)
 
     def after_cluster_step(self, step, cluster, loss):
         twin_loss = self.twin.train_step()
-        if float(twin_loss) != float(loss):
-            self.fail(f"step {step}: loss diverged from legacy oracle "
-                      f"(fast={float(loss)!r}, legacy={float(twin_loss)!r})")
+        a, b = float(loss), float(twin_loss)
+        if abs(a - b) > self.LOSS_ATOL + self.LOSS_RTOL * abs(b):
+            self.fail(f"step {step}: loss diverged across {self.axis} "
+                      f"beyond tolerance ({a!r} vs {b!r})")
         self._compare_state(f"step {step} after train_step", cluster)
+
+    def param_atol(self, cl) -> float:
+        return self.PARAM_ATOL0 + 2.0 * cl.adam.lr * cl.opt_step
 
     def _compare_state(self, where: str, cl):
         from .statespace import COMPONENTS
@@ -134,6 +154,7 @@ class ParameterConsistencyChecker(InvariantChecker):
             self.fail(f"{where}: per-rank micro-batch sizes diverged")
         if list(cl.grad_weights) != list(tw.grad_weights):
             self.fail(f"{where}: gradient weights diverged")
+        atol = self.param_atol(cl)
         for p, (st, ts) in enumerate(zip(cl.stages, tw.stages)):
             if (list(st.entries) != list(ts.entries)
                     or list(st.sizes) != list(ts.sizes)
@@ -142,16 +163,41 @@ class ParameterConsistencyChecker(InvariantChecker):
             for comp in COMPONENTS:
                 a = cl._stage_full_vec(st, comp)
                 b = tw._stage_full_vec(ts, comp)
-                if not np.array_equal(a, b):
-                    i = int(np.flatnonzero(a != b)[0])
-                    self.fail(f"{where}: stage {p} {comp} full vector "
-                              f"diverged from legacy oracle at element {i} "
-                              f"({a[i]!r} vs {b[i]!r})")
-                for r in st.dp_ranks:
-                    if not np.array_equal(st.shard(r)[comp],
-                                          ts.shard(r)[comp]):
-                        self.fail(f"{where}: stage {p} rank {r} {comp} shard "
-                                  f"diverged from legacy oracle")
+                if not np.allclose(a, b, rtol=self.PARAM_RTOL, atol=atol):
+                    err = np.abs(a - b) - atol - self.PARAM_RTOL * np.abs(b)
+                    i = int(np.argmax(err))
+                    self.fail(
+                        f"{where}: stage {p} {comp} diverged across "
+                        f"{self.axis} beyond tolerance (element {i}: "
+                        f"{a[i]!r} vs {b[i]!r}, atol={atol:.3e} after "
+                        f"{cl.opt_step} optimizer steps)")
+
+
+class ParameterConsistencyChecker(_TwinChecker):
+    """Twin-oracle lockstep against the opposite code path
+    (``fast_path=False`` = the preserved ``core/legacy.py`` seed
+    implementation), plus an exact re-derivation of every rank's shard from
+    the stage's reassembled master through the pure-Python ``zero.Layout``
+    ownership map.
+
+    The two paths are different XLA programs (one vmap-batched grad against
+    per-item jits), which reorder float32 reductions: under jax 0.9.0 their
+    losses already differ in the last bits at step 0, and a TPU tiles them
+    differently again.  So the contract is a declared tolerance, not bit
+    identity: the loss within ``LOSS_RTOL`` — about a hundred float32 ulps
+    (eps 1.2e-7) of a mean over tokens of a log-softmax, far above the
+    observed ~2e-8 — and the state within the Adam drift bound above."""
+
+    name = "parameter-consistency"
+    axis = "code paths (fast vs legacy)"
+    LOSS_RTOL = 1e-5
+
+    def make_twin(self, runner, cluster):
+        return runner.workload.make_cluster(fast_path=not cluster.fast_path)
+
+    def _compare_state(self, where: str, cl):
+        super()._compare_state(where, cl)
+        for p, st in enumerate(cl.stages):
             self._check_layout(where, p, st)
 
     def _check_layout(self, where: str, p: int, st):
@@ -171,48 +217,27 @@ class ParameterConsistencyChecker(InvariantChecker):
                               f"does not match zero.Layout reassembly")
 
 
-# ---------------------------------------------------------------------------
-# 1b. kernel consistency (pallas-mode parameter consistency, tolerance tiers)
-# ---------------------------------------------------------------------------
-class KernelConsistencyChecker(InvariantChecker):
-    """Pallas-mode replacement for the bit-exact parameter twin.
-
-    The Pallas kernels are numerically equivalent but not bit-identical to
-    plain jnp (blocked online softmax, chunked scan), so a pallas-mode trace
-    cannot be held to float ``==``.  This checker relaxes invariant 1 to the
-    *declared* tolerance instead of dropping it:
-
-    * at cluster start, every kernel is spot-checked against its
-      ``kernels/ref.py`` oracle under ``kernels.ops.TOLERANCE_TIERS``
-      (the corpus in ``kernels/check.py``);
-    * a ``use_pallas``-flipped twin cluster (plain jnp, same fast_path)
-      receives the identical event/step sequence; structure (layer
-      assignment, dataflow shape, stage entries/sizes/dp_ranks) and the
-      control-plane recovery-record fields stay EXACT, while losses and the
-      master/mu/nu state vectors are compared under a tolerance that grows
-      with the optimizer step count — each Adam step can move an element of
-      the two runs apart by at most ~2*lr (sign flip of the bounded update)
-      plus the forward tolerance, so ``atol = ATOL0 + 2*lr*opt_step``.
-      Observed drift on the fuzz corpus is orders of magnitude below this
-      bound (the kernels' custom VJPs backpropagate exact oracle gradients).
-
-    Note the bit-exact fast/legacy ``ParameterConsistencyChecker`` remains
-    valid in pallas mode (both paths share ``_loss_fn``, hence the same
-    kernels); this checker covers the pallas-vs-jnp axis.
-    """
+class KernelConsistencyChecker(_TwinChecker):
+    """Pallas-mode parameter consistency: a ``use_pallas``-flipped twin
+    (plain jnp, same fast_path), after a spot check of every kernel against
+    its ``kernels/ref.py`` oracle under ``kernels.ops.TOLERANCE_TIERS`` (the
+    corpus in ``kernels/check.py``).  The kernels' forward differs from jnp
+    by up to their tier (blocked online softmax, chunked scan), so the loss
+    bound is the flash-attention tier's rtol.  Observed drift on the fuzz
+    corpus is orders of magnitude below the bounds (the kernels' custom VJPs
+    backpropagate exact oracle gradients)."""
 
     name = "kernel-consistency"
-
+    axis = "kernel modes"
     LOSS_RTOL = 1e-4
     LOSS_ATOL = 1e-6
     PARAM_RTOL = 1e-4
-    PARAM_ATOL0 = 1e-5
 
     def __init__(self, spot_check: bool = True):
-        self.twin = None
+        super().__init__()
         self.spot_check = spot_check
 
-    def on_cluster_start(self, runner, cluster):
+    def make_twin(self, runner, cluster):
         if self.spot_check:
             from repro.kernels.check import check_kernels
             for row in check_kernels(seed=0):
@@ -221,57 +246,7 @@ class KernelConsistencyChecker(InvariantChecker):
                         f"kernel-vs-ref spot check failed: {row['case']} "
                         f"max_abs_err={row['max_abs_err']:.3e} exceeds tier "
                         f"rtol={row['rtol']} atol={row['atol']}")
-        self.twin = runner.workload.make_cluster(
-            use_pallas=not cluster.use_pallas)
-        self._compare_state("start", cluster)
-
-    def after_cluster_event(self, step, event, cluster, record):
-        twin_rec = self.twin.apply_event(event)
-        for k in ("detect", "communicator", "rng_moves"):
-            if twin_rec.get(k) != record.get(k):
-                self.fail(f"step {step} {event.describe()}: recovery record "
-                          f"field {k!r} diverged across kernel modes "
-                          f"({record.get(k)!r} vs {twin_rec.get(k)!r})")
-        self._compare_state(f"step {step} after {event.describe()}", cluster)
-
-    def after_cluster_step(self, step, cluster, loss):
-        twin_loss = self.twin.train_step()
-        a, b = float(loss), float(twin_loss)
-        if abs(a - b) > self.LOSS_ATOL + self.LOSS_RTOL * abs(b):
-            self.fail(f"step {step}: loss diverged across kernel modes "
-                      f"beyond tolerance ({a!r} vs {b!r})")
-        self._compare_state(f"step {step} after train_step", cluster)
-
-    def _param_atol(self, cl) -> float:
-        return self.PARAM_ATOL0 + 2.0 * cl.adam.lr * cl.opt_step
-
-    def _compare_state(self, where: str, cl):
-        from .statespace import COMPONENTS
-        tw = self.twin
-        if cl.layer_assignment != tw.layer_assignment:
-            self.fail(f"{where}: layer assignment diverged "
-                      f"({cl.layer_assignment} vs {tw.layer_assignment})")
-        if list(cl.per_rank_mbs) != list(tw.per_rank_mbs):
-            self.fail(f"{where}: per-rank micro-batch sizes diverged")
-        if list(cl.grad_weights) != list(tw.grad_weights):
-            self.fail(f"{where}: gradient weights diverged")
-        atol = self._param_atol(cl)
-        for p, (st, ts) in enumerate(zip(cl.stages, tw.stages)):
-            if (list(st.entries) != list(ts.entries)
-                    or list(st.sizes) != list(ts.sizes)
-                    or list(st.dp_ranks) != list(ts.dp_ranks)):
-                self.fail(f"{where}: stage {p} structure diverged")
-            for comp in COMPONENTS:
-                a = cl._stage_full_vec(st, comp)
-                b = tw._stage_full_vec(ts, comp)
-                if not np.allclose(a, b, rtol=self.PARAM_RTOL, atol=atol):
-                    err = np.abs(a - b) - atol - self.PARAM_RTOL * np.abs(b)
-                    i = int(np.argmax(err))
-                    self.fail(
-                        f"{where}: stage {p} {comp} diverged across kernel "
-                        f"modes beyond tolerance (element {i}: {a[i]!r} vs "
-                        f"{b[i]!r}, atol={atol:.3e} after {cl.opt_step} "
-                        f"optimizer steps)")
+        return runner.workload.make_cluster(use_pallas=not cluster.use_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -560,10 +535,9 @@ class MttrThroughputChecker(InvariantChecker):
 def default_cluster_checkers(use_pallas: bool = False) -> List[InvariantChecker]:
     """The four paper guarantees for numeric (VirtualCluster) traces.
 
-    ``use_pallas=True`` swaps the bit-exact fast/legacy parameter twin for
-    the tolerance-tier :class:`KernelConsistencyChecker` (pallas/jnp twin) —
-    invariant 1 relaxed to the kernels' declared tolerance, the other three
-    unchanged."""
+    ``use_pallas=True`` swaps the fast/legacy parameter twin for the
+    :class:`KernelConsistencyChecker` (pallas/jnp twin) — invariant 1 held
+    to the kernels' declared tolerance, the other three unchanged."""
     param: InvariantChecker = (KernelConsistencyChecker() if use_pallas
                                else ParameterConsistencyChecker())
     return [param, DataflowConsistencyChecker(),

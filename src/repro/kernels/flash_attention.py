@@ -10,8 +10,9 @@ e.g. bq=bk=128, d=128 -> ~4 * 128*128*4B ≈ 256 KiB — comfortably within the
 ~16 MiB v5e VMEM with double buffering.
 
 GQA is handled by the ops.py wrapper (kv heads broadcast to q heads before
-the call; the kernel itself is MHA).  Validated in interpret mode against
-ref.mha_reference (CPU backend has no TPU lowering — see DESIGN.md §5).
+the call; the kernel itself is MHA).  Checked against ref.mha_reference in
+interpret mode on the CPU and compiled by Mosaic on a TPU (``interpret`` is
+chosen by ``ops.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -23,6 +24,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# f32 operands: ask Mosaic for a full f32 contraction (its default may round
+# them to bf16), so the kernel meets its f32 tier on the chip as it does in
+# the interpreter
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -45,6 +50,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         k = k_ref[...].astype(jnp.float32)         # [bk, d]
         v = v_ref[...].astype(jnp.float32)         # [bk, d]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=_F32,
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                            # [bq, bk]
         if causal:
@@ -59,7 +65,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((1,), (0,)), ((), ())), precision=_F32,
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
         l_ref[...] = l_new
 
@@ -78,7 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 def flash_attention_kernel(q, k, v, *, causal: bool = True,
                            sm_scale: float | None = None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q, k, v: [BH, S, d] (MHA, heads pre-folded into batch).  -> [BH, S, d]."""
     BH, S, d = q.shape
     assert k.shape == (BH, S, d) and v.shape == (BH, S, d)

@@ -4,13 +4,13 @@ PR 2 rejected a jitted fused Adam: XLA contracts the ``b1*mu + (1-b1)*g``
 mul+add chains into FMAs, breaking bit-identity with the host-numpy oracle
 (``optim.adam.adam_update_flat_np``).  A Pallas kernel controls the
 arithmetic order instead: on TPU each jnp op in the kernel body lowers to a
-distinct Mosaic VPU op (no cross-statement FMA contraction).  In interpret
-mode (this container) the Pallas interpreter still compiles the body, so
-the result is within ~1 ulp per op of the numpy oracle rather than
-bit-identical — validated against ``optim.adam.adam_update_flat_np`` under
+distinct Mosaic VPU op (no cross-statement FMA contraction).  Off the TPU
+the Pallas interpreter still compiles the body through XLA, so the result
+is within ~1 ulp per op of the numpy oracle rather than bit-identical —
+checked against ``optim.adam.adam_update_flat_np`` under
 ``ops.TOLERANCE_TIERS["fused_adam"]`` (~10x observed margin) in
-tests/test_kernels.py and timed by ``benchmarks/kernel_ref.py``.  The
-bit-exactness claim is a TPU/Mosaic property to be verified on hardware.
+tests/test_kernels.py and timed by ``benchmarks/kernel_ref.py``.  On the
+chip ``chip_smoke.py`` holds the Mosaic build to the same tier.
 
 First cut: a bench/oracle kernel, NOT wired into the VirtualCluster hot
 path (the host-numpy fused update stays the production path; its bit
@@ -52,7 +52,7 @@ def _fused_adam_body(g_ref, m_ref, mu_ref, nu_ref, m_out, mu_out, nu_out, *,
 def fused_adam_kernel(grad, master, mu, nu, *, b1: float, b2: float,
                       eps: float, lr: float, weight_decay: float,
                       b1t: float, b2t: float, block_rows: int = 256,
-                      interpret: bool = True):
+                      interpret: bool):
     """grad/master/mu/nu: flat f32 [n]. Returns (master, mu, nu), f32 [n]."""
     n = grad.size
     cols = min(_LANES, max(n, 1))

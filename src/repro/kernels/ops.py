@@ -1,8 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On this CPU container the kernels always run in interpret mode (Pallas TPU
-lowering requires a TPU backend); on a real TPU deployment set
-REPRO_PALLAS_INTERPRET=0.  The wrappers adapt model-layer layouts (GQA head
+The platform picks how a kernel runs: Mosaic compiles it when the default
+backend is a TPU, and the Pallas interpreter runs it anywhere else (the CPU
+tests).  ``interpret_mode`` makes that choice while the wrapper is traced,
+never at import.  The wrappers adapt model-layer layouts (GQA head
 broadcast, group broadcast) to the kernels' MHA/per-head forms, and validate
 the layout contracts (head/group divisibility, unsupported initial state)
 with crisp ``ValueError``s — shape checks are static, so they fire at trace
@@ -22,7 +23,6 @@ empirically with margin over the deterministic test/fuzz corpus.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +33,12 @@ from .fused_adam import fused_adam_kernel
 from .rmsnorm import rmsnorm_kernel
 from .ssd_scan import ssd_scan_kernel
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def interpret_mode() -> bool:
+    """True unless the default backend is a TPU: Pallas TPU kernels lower
+    through Mosaic only there.  Read at trace time."""
+    return jax.default_backend() != "tpu"
+
 
 # ---------------------------------------------------------------------------
 # custom VJPs: Pallas forward, jnp-reference backward.
@@ -49,7 +54,7 @@ _INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_mha(qf, kf, vf, causal, block_q, block_k):
     return flash_attention_kernel(qf, kf, vf, causal=causal, block_q=block_q,
-                                  block_k=block_k, interpret=_INTERPRET)
+                                  block_k=block_k, interpret=interpret_mode())
 
 
 def _flash_mha_fwd(qf, kf, vf, causal, block_q, block_k):
@@ -68,7 +73,7 @@ _flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _rmsnorm_p(x, scale, eps):
-    return rmsnorm_kernel(x, scale, eps=eps, interpret=_INTERPRET)
+    return rmsnorm_kernel(x, scale, eps=eps, interpret=interpret_mode())
 
 
 def _rmsnorm_fwd(x, scale, eps):
@@ -88,7 +93,7 @@ _rmsnorm_p.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _ssd_p(x, dt, A, Bh, Ch, chunk):
     return ssd_scan_kernel(x, dt, A, Bh, Ch, chunk=chunk,
-                           interpret=_INTERPRET)
+                           interpret=interpret_mode())
 
 
 def _ssd_fwd(x, dt, A, Bh, Ch, chunk):
@@ -186,4 +191,4 @@ def fused_adam(grad, master, mu, nu, *, step: int, b1: float = 0.9,
     b2t = 1.0 - b2 ** step
     return fused_adam_kernel(grad, master, mu, nu, b1=b1, b2=b2, eps=eps,
                              lr=lr, weight_decay=weight_decay, b1t=b1t,
-                             b2t=b2t, interpret=_INTERPRET)
+                             b2t=b2t, interpret=interpret_mode())

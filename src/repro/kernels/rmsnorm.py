@@ -19,8 +19,14 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     o_ref[...] = (x * jax.lax.rsqrt(var + eps) * s_ref[...]).astype(o_ref.dtype)
 
 
-def rmsnorm_kernel(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
-                   interpret: bool = True):
+#: f32 bytes of one row block.  The input and output blocks are double-
+#: buffered and the body holds a few f32 temporaries of the block's size, so
+#: 1 MiB keeps a program well inside v5e's 16 MiB of scoped VMEM (256 rows of
+#: d=4096 in f32 ran out of it).
+_BLOCK_F32_BYTES = 1 << 20
+
+
+def rmsnorm_kernel(x, scale, *, eps: float = 1e-5, interpret: bool):
     """x: [..., d]; scale: [d]."""
     orig_shape = x.shape
     d = x.shape[-1]
@@ -28,7 +34,7 @@ def rmsnorm_kernel(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
     for s in x.shape[:-1]:
         rows *= s
     x2 = x.reshape(rows, d)
-    block_rows = min(block_rows, rows)
+    block_rows = min(max(8, _BLOCK_F32_BYTES // (4 * d) // 8 * 8), rows)
     # pad rows to a multiple of block_rows
     pad = (-rows) % block_rows
     if pad:

@@ -4,6 +4,9 @@ jax import, and the argparse help reuses it)."""
 import os
 os.environ["XLA_FLAGS"] = os.environ.get("DRYRUN_XLA_FLAGS",
                                          "--xla_force_host_platform_device_count=512")
+# the 512 devices are virtual CPUs: pin the platform so neither this process
+# nor the --all children it starts ever open an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import: jax locks the device count on first init.
 
 _DOC = """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.

@@ -38,10 +38,12 @@ def main():
     import jax
     import numpy as np
     from repro import configs
+    from repro.compile_cache import enable_compile_cache
     from repro.data.pipeline import GlobalBatchSampler, make_batch
     from repro.models import registry as R
     from repro.optim.adam import AdamConfig, adam_update, init_opt_state
 
+    enable_compile_cache()
     cfg = configs.get_smoke_config(args.arch) if args.smoke else \
         configs.get_config(args.arch)
     print(f"training {cfg.name}: {cfg.param_count() / 1e6:.1f}M params")

@@ -37,14 +37,19 @@ def _maybe_seq_shard(x, cfg: ModelConfig):
     """SP-style activation constraint: shard the sequence dim over `model`
     between blocks, so XLA lowers TP boundary all-reduces as reduce-scatter +
     all-gather pairs (half the wire volume, overlappable)."""
-    if not cfg.seq_shard_acts:
-        return x
+    if not cfg.seq_shard_acts or not _mesh_in_scope():
+        return x          # no mesh in scope (unit tests): nothing to shard
     from jax.sharding import PartitionSpec as P
     U = P.UNCONSTRAINED
-    try:
-        return jax.lax.with_sharding_constraint(x, P(U, "model", U))
-    except Exception:     # no mesh in scope (unit tests)
-        return x
+    return jax.lax.with_sharding_constraint(x, P(U, "model", U))
+
+
+def _mesh_in_scope() -> bool:
+    """A mesh set by ``jax.set_mesh`` or entered as a ``with mesh:``
+    context (the dry-run's form)."""
+    from jax._src import mesh as mesh_lib
+    return not (jax.sharding.get_abstract_mesh().empty
+                and mesh_lib.thread_resources.env.physical_mesh.empty)
 
 
 # --------------------------------------------------------------------------

@@ -367,7 +367,7 @@ def make_pallas_case(seed: int) -> FuzzCase:
     """Pallas-mode cluster fuzzing: same trace grammar with the kernels in
     the training hot path.  ``run_case`` sees ``workload.use_pallas`` and
     swaps in the tolerance-tier :class:`KernelConsistencyChecker` for the
-    bit-exact parameter twin.  Interpret-mode kernels are slow, so traces
+    fast/legacy parameter twin.  Interpret-mode kernels are slow, so traces
     are shorter than plain cluster mode."""
     rnd = random.Random(f"pallas-{seed}")
     w = dataclasses.replace(draw_cluster_workload(rnd),
@@ -487,8 +487,8 @@ def shrink_case(case: FuzzCase,
 # * ``flap_only`` — no real failures at all; every eviction the controller
 #   commits is by definition a false positive and must be healed by the end
 #   of the settle window.  Runs under the FULL four-checker stack (the
-#   bit-exact parameter twin receives the identical event sequence, so even
-#   a false eviction + rejoin must keep state bit-identical).
+#   parameter twin receives the identical event sequence, so even a false
+#   eviction + rejoin must keep state within its declared tolerance).
 # * ``mixed``    — real kills and preemption notices interleaved with probe
 #   chaos; the controller must evict the dead, drain the doomed, and heal
 #   everything else.

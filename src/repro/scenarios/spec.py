@@ -12,8 +12,8 @@ Two workload descriptions exist because the runner has two execution modes
 (see :mod:`repro.scenarios.runner`):
 
 * :class:`ClusterWorkload` — a tiny real model driven numerically on the
-  :class:`~repro.core.cluster.VirtualCluster` (losses, live remap, bit-exact
-  consistency checks);
+  :class:`~repro.core.cluster.VirtualCluster` (losses, live remap,
+  twin consistency checks);
 * :class:`AnalyticWorkload` — a paper-scale workload (e.g. Llama-2 on 96
   NPUs) evaluated through the recovery policies and cost models only.
 """
@@ -49,7 +49,7 @@ class ClusterWorkload:
 
     def make_cluster(self, **overrides):
         """Build the VirtualCluster.  ``overrides`` pass straight through to
-        the constructor — e.g. ``fast_path=False`` builds the bit-exact
+        the constructor — e.g. ``fast_path=False`` builds the
         ``core/legacy.py`` twin the invariant harness locksteps against, and
         ``use_pallas=False`` builds the plain-jnp twin the tolerance-tier
         kernel checker compares a pallas-mode run against."""

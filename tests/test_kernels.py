@@ -26,7 +26,8 @@ class TestFlashAttention:
         q = jax.random.normal(jax.random.fold_in(KEY, 1), (BH, S, hd), dtype)
         k = jax.random.normal(jax.random.fold_in(KEY, 2), (BH, S, hd), dtype)
         v = jax.random.normal(jax.random.fold_in(KEY, 3), (BH, S, hd), dtype)
-        o = flash_attention_kernel(q, k, v, causal=True, block_q=64, block_k=64)
+        o = flash_attention_kernel(q, k, v, causal=True, block_q=64, block_k=64,
+                                   interpret=True)
         o_ref = ref.mha_reference(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(o, np.float32),
                                    np.asarray(o_ref, np.float32), **_tol(dtype))
@@ -38,7 +39,8 @@ class TestFlashAttention:
         q = jax.random.normal(jax.random.fold_in(KEY, 4), (2, S, 64))
         k = jax.random.normal(jax.random.fold_in(KEY, 5), (2, S, 64))
         v = jax.random.normal(jax.random.fold_in(KEY, 6), (2, S, 64))
-        o = flash_attention_kernel(q, k, v, block_q=bq, block_k=bk)
+        o = flash_attention_kernel(q, k, v, block_q=bq, block_k=bk,
+                                   interpret=True)
         o_ref = ref.mha_reference(q, k, v)
         np.testing.assert_allclose(o, o_ref, rtol=2e-5, atol=2e-5)
 
@@ -46,7 +48,8 @@ class TestFlashAttention:
         q = jax.random.normal(jax.random.fold_in(KEY, 7), (2, 128, 64))
         k = jax.random.normal(jax.random.fold_in(KEY, 8), (2, 128, 64))
         v = jax.random.normal(jax.random.fold_in(KEY, 9), (2, 128, 64))
-        o = flash_attention_kernel(q, k, v, causal=False, block_q=64, block_k=64)
+        o = flash_attention_kernel(q, k, v, causal=False, block_q=64, block_k=64,
+                                   interpret=True)
         np.testing.assert_allclose(o, ref.mha_reference(q, k, v, causal=False),
                                    rtol=2e-5, atol=2e-5)
 
@@ -68,7 +71,7 @@ class TestRmsnorm:
     def test_vs_oracle(self, shape, dtype):
         x = jax.random.normal(jax.random.fold_in(KEY, 20), shape, dtype)
         s = jax.random.normal(jax.random.fold_in(KEY, 21), (shape[-1],))
-        o = rmsnorm_kernel(x, s)
+        o = rmsnorm_kernel(x, s, interpret=True)
         np.testing.assert_allclose(np.asarray(o, np.float32),
                                    np.asarray(ref.rmsnorm_reference(x, s),
                                               np.float32), **_tol(dtype))
@@ -84,7 +87,7 @@ class TestSsdScan:
         A = -jnp.exp(jax.random.normal(jax.random.fold_in(KEY, 32), (h,)) * 0.3)
         Bm = jax.random.normal(jax.random.fold_in(KEY, 33), (b, s, h, n)) * 0.5
         Cm = jax.random.normal(jax.random.fold_in(KEY, 34), (b, s, h, n)) * 0.5
-        y = ssd_scan_kernel(x, dt, A, Bm, Cm, chunk=chunk)
+        y = ssd_scan_kernel(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
         y_ref, _ = ref.ssd_reference(x, dt, A, Bm, Cm)
         np.testing.assert_allclose(y, y_ref, rtol=5e-5, atol=5e-5)
 

@@ -94,9 +94,6 @@ print(json.dumps({{"ok": True, "flops": compiled.cost_analysis()["flops"]}}))
 """
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="reduced-mesh lowering needs jax.sharding.AxisType"
-                           " (jax >= 0.5); installed jax is older")
 @pytest.mark.parametrize("family,shape,seq", [
     ("dense", "train_4k", 64),
     ("moe", "train_4k", 64),

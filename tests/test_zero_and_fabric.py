@@ -9,7 +9,8 @@ except ImportError:   # container lacks hypothesis -> deterministic stub
 from repro.core import zero
 from repro.core.fabric.remap import IntegrityError, LiveRemap
 from repro.core.fabric.snapshot import SnapshotPool
-from repro.optim.adam import AdamConfig, adam_update_flat
+from repro.optim.adam import (AdamConfig, adam_update_flat,
+                              adam_update_flat_np)
 
 
 # -------------------------------------------------------------- zero layout --
@@ -171,3 +172,24 @@ class TestLiveRemap:
         rm = LiveRemap()
         with pytest.raises(IntegrityError):
             rm.integrity_check(100, {0: [(0, 40)]}, {1: [(50, 100)]})
+
+
+# ------------------------------------------------------------- host adam --
+@pytest.mark.parametrize("step", [1, 7])
+def test_host_adam_bit_identical_to_eager(step):
+    """The in-place numpy AdamW keeps the eager op sequence: same bits."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(step)
+    n = 4097
+    g = rng.standard_normal(n).astype(np.float32)
+    state = {"master": rng.standard_normal(n).astype(np.float32),
+             "mu": (rng.standard_normal(n) * 0.01).astype(np.float32),
+             "nu": np.abs(rng.standard_normal(n) * 0.01).astype(np.float32)}
+    before = {k: v.copy() for k, v in state.items()}
+    got = adam_update_flat_np(g, state, step, AdamConfig())
+    _, want = adam_update_flat(jnp.asarray(g),
+                               {k: jnp.asarray(v) for k, v in state.items()},
+                               step, AdamConfig())
+    for k in want:
+        np.testing.assert_array_equal(got[k], np.asarray(want[k]))
+        np.testing.assert_array_equal(state[k], before[k])   # input intact
