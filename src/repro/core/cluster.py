@@ -52,6 +52,7 @@ from repro.models import registry as R
 from repro.models.config import ModelConfig
 from repro.models.layers import RngCtx
 from repro.optim.adam import AdamConfig, adam_update_flat_np
+from repro.spans import span
 from . import legacy
 from .agent import Agent, Probe
 from .clusterview import GroupDelta
@@ -61,7 +62,7 @@ from .cost_model import HardwareSpec, SegmentCosts
 from .engine import RecoveryPlan, ScheduleEngine
 from .events import ElasticEvent, EventKind
 from .fabric.remap import LiveRemap, RemapPlan
-from .fabric.snapshot import SnapshotPool
+from .fabric.snapshot import ADAM_STATE_BYTES, SnapshotPool
 from .migration import MigrationSpec, migration_timing
 from .pipeline import StageTiming, simulate_1f1b
 from .statespace import (COMPONENTS, HEAD, STEM, EntryFlattener, StageState,
@@ -225,12 +226,14 @@ class VirtualCluster:
         # model-flat buffer) + ONE jitted model unravel (a single
         # host->device transfer for the whole model)
         vec = np.empty(sum(st.total for st in self.stages), dtype=np.float32)
-        off = 0
-        for st in self.stages:
-            st.table.scatter(st.flat["master"], out=vec[off:off + st.total])
-            off += st.total
-        self.stem, self.layer_params, self.head = \
-            self.flattener.unflatten_model(vec)
+        with span("step.writeback", nbytes=vec.nbytes):
+            off = 0
+            for st in self.stages:
+                st.table.scatter(st.flat["master"],
+                                 out=vec[off:off + st.total])
+                off += st.total
+            self.stem, self.layer_params, self.head = \
+                self.flattener.unflatten_model(vec)
 
     # ------------------------------------------------------------------
     # training math
@@ -289,8 +292,9 @@ class VirtualCluster:
 
     def _grad_calls(self, step: int):
         """The step's micro-items ``(rank, sample ids)`` in the seed's
-        (micro, rank) order, and one ``(item indices, jitted fn, args)``
-        call per micro-batch size bucket (uneven after a failure)."""
+        (micro, rank) order, one ``(item indices, jitted fn, args)`` call
+        per micro-batch size bucket (uneven after a failure), and the bytes
+        of tokens and sample ids uploaded for them."""
         ids_by_rank = self.sampler.partition(step, self.per_rank_mbs,
                                              self.num_micro)
         items: List[Tuple[int, np.ndarray]] = []    # (rank, ids), seed order
@@ -302,7 +306,7 @@ class VirtualCluster:
         buckets: Dict[int, List[int]] = {}
         for k, (r, ids) in enumerate(items):
             buckets.setdefault(len(ids), []).append(k)
-        calls = []
+        calls, nbytes = [], 0
         for B, idxs in buckets.items():
             # one hash-materialization for the whole bucket (elementwise in
             # (sample_id, position), so reshape == per-item materialize)
@@ -320,7 +324,8 @@ class VirtualCluster:
             args = (self.stem, self.layer_params, self.head, jt, jt,
                     self.base_key, np.uint32(step), jnp.asarray(sids))
             calls.append((idxs, self._batched_grad_fn(B, len(idxs)), args))
-        return items, calls
+            nbytes += toks.nbytes + sids.nbytes
+        return items, calls, nbytes
 
     def _micro_grads(self, step: int) -> Tuple[float, np.ndarray]:
         """Weighted accumulation over micro-batches and DP slices — the
@@ -333,14 +338,20 @@ class VirtualCluster:
         accumulate host-side in the seed's exact (micro, rank) order.
         Returns ``(total_loss, model-flat gradient)``.
         """
-        items, calls = self._grad_calls(step)
+        with span("step.inputs") as sp:
+            items, calls, nbytes = self._grad_calls(step)
+            sp.set_metadata(nbytes=nbytes)
         n = len(items)
         loss_rows: List[Any] = [None] * n
         flat_rows: List[Any] = [None] * n
         for idxs, fn, args in calls:
             # one device_get per bucket (exactly one per step in the even-
-            # split common case) for all losses + flat grads together
-            losses, flats = jax.device_get(fn(*args))
+            # split common case) for all losses + flat grads together; the
+            # wait before it parts device time from the copy to the host
+            with span("step.device", items=len(idxs)):
+                out = jax.block_until_ready(fn(*args))
+            with span("step.fetch", nbytes=sum(x.nbytes for x in out)):
+                losses, flats = jax.device_get(out)
             for i, k in enumerate(idxs):
                 loss_rows[k] = losses[i]
                 flat_rows[k] = flats[i]
@@ -349,11 +360,12 @@ class VirtualCluster:
         # per-leaf jnp ops (IEEE correctly-rounded either way)
         acc = None
         total_loss = 0.0
-        for k, (r, _ids) in enumerate(items):
-            w = self.grad_weights[r] / self.num_micro
-            gw = flat_rows[k] * np.float32(w)
-            acc = gw if acc is None else acc + gw
-            total_loss += float(loss_rows[k]) * w
+        with span("step.accumulate", elements=sum(f.size for f in flat_rows)):
+            for k, (r, _ids) in enumerate(items):
+                w = self.grad_weights[r] / self.num_micro
+                gw = flat_rows[k] * np.float32(w)
+                acc = gw if acc is None else acc + gw
+                total_loss += float(loss_rows[k]) * w
         return total_loss, acc
 
     def compile_step(self) -> List[Any]:
@@ -361,38 +373,49 @@ class VirtualCluster:
         (one per micro-batch size bucket) ahead of it and return them; the
         step then finds them in the jit cache.  Lets a caller time the
         compile apart from the step and read the compiled program."""
-        _, calls = self._grad_calls(self.step_count)
-        return [fn.lower(*args).compile() for _, fn, args in calls]
+        _, calls, _ = self._grad_calls(self.step_count)
+        programs = []
+        for _, fn, args in calls:
+            with span("compile.lower"):
+                lowered = fn.lower(*args)
+            with span("compile.compile"):
+                programs.append(lowered.compile())
+        return programs
 
     def train_step(self) -> float:
         if not self.fast_path:
             return legacy.train_step(self)
         step = self.step_count
-        loss, gflat = self._micro_grads(step)
-        self.opt_step += 1
-        grad_shard_by_stage: List[List[np.ndarray]] = []
-        off = 0
-        for st in self.stages:
-            # this stage's slice of the model-flat gradient, permuted to
-            # shard order with one fancy-index
-            gstage = gflat[off:off + st.total]
-            off += st.total
-            tbl = st.table
-            gshard = tbl.gather(gstage)
-            grad_shard_by_stage.append(tbl.split(gshard))
-            if st.total:
-                # ONE fused host-side Adam update over the stage's flat
-                # buffers (bit-identical to the seed's per-shard eager
-                # updates); the per-rank shards are views into the result
-                st.flat = adam_update_flat_np(gshard, st.flat, self.opt_step,
-                                              self.adam)
-        self._write_params_from_masters()
-        if self.snapshot_enabled:
-            for p in range(self.pp):
-                self.snapshots[p].snapshot_step(step, grad_shard_by_stage[p],
-                                                self.opt_step)
-        self.step_count += 1
-        self.losses.append(loss)
+        with span("step", step=step):
+            loss, gflat = self._micro_grads(step)
+            self.opt_step += 1
+            grad_shard_by_stage: List[List[np.ndarray]] = []
+            off = 0
+            for p, st in enumerate(self.stages):
+                with span("step.adam", elements=st.total, stage=p):
+                    # this stage's slice of the model-flat gradient, permuted
+                    # to shard order with one fancy-index
+                    gstage = gflat[off:off + st.total]
+                    off += st.total
+                    tbl = st.table
+                    gshard = tbl.gather(gstage)
+                    grad_shard_by_stage.append(tbl.split(gshard))
+                    if st.total:
+                        # ONE fused host-side Adam update over the stage's
+                        # flat buffers (bit-identical to the seed's per-shard
+                        # eager updates); the per-rank shards are views into
+                        # the result
+                        st.flat = adam_update_flat_np(gshard, st.flat,
+                                                      self.opt_step, self.adam)
+            self._write_params_from_masters()
+            if self.snapshot_enabled:
+                for p in range(self.pp):
+                    with span("step.snapshot") as sp:
+                        stats = self.snapshots[p].snapshot_step(
+                            step, grad_shard_by_stage[p], self.opt_step)
+                        sp.set_metadata(nbytes=stats.grad_bytes_sent)
+            self.step_count += 1
+            self.losses.append(loss)
         return loss
 
     # ------------------------------------------------------------------
@@ -432,24 +455,25 @@ class VirtualCluster:
         The loop bound is the controller's worst-case confirmation threshold
         (``max_confirm_misses``), not the bare miss limit: a rank that
         flapped earlier has an exponentially backed-off bar to clear."""
-        probes = []
-        base_t = self.simulate_step_time()
-        for d in range(self.dp0):
-            for p in range(self.pp):
-                rank = d * self.pp + p
-                probes.append(Probe(self.step_count, rank,
-                                    heartbeat=bool(self.alive[d, p]),
-                                    step_seconds=base_t * self.slow[d, p],
-                                    mem_used=float(self.mem_used[d, p])))
-        events: List[ElasticEvent] = []
-        for _ in range(self.controller.max_confirm_misses()):
-            events = self.controller.observe(probes)
-            if events:
-                break
-        if not events:
-            return None
-        ev = events[0]
-        return self.apply_event(ev)
+        with span("recover"):
+            with span("recover.detect"):
+                probes = []
+                base_t = self.simulate_step_time()
+                for d in range(self.dp0):
+                    for p in range(self.pp):
+                        probes.append(Probe(
+                            self.step_count, d * self.pp + p,
+                            heartbeat=bool(self.alive[d, p]),
+                            step_seconds=base_t * self.slow[d, p],
+                            mem_used=float(self.mem_used[d, p])))
+                events: List[ElasticEvent] = []
+                for _ in range(self.controller.max_confirm_misses()):
+                    events = self.controller.observe(probes)
+                    if events:
+                        break
+            if not events:
+                return None
+            return self.apply_event(events[0])
 
     def apply_event(self, ev: ElasticEvent) -> Dict[str, float]:
         """Recovery Executor entry point: one elastic event -> itemized MTTR.
@@ -512,11 +536,12 @@ class VirtualCluster:
                 f"rank {rank} (dp={d}, stage={p}) was already removed from "
                 f"the stage's DP group; scenario traces must not re-fail a "
                 f"recovered rank")
-        self.alive[d, p] = False
-        old_sample_rank = self._current_sample_assignment()
-        return self.engine.plan_view(
-            ev, self.build_view(), failed_dp_ranks=[d],
-            old_sample_rank=old_sample_rank, dp=len(st.dp_ranks))
+        with span("recover.plan"):
+            self.alive[d, p] = False
+            old_sample_rank = self._current_sample_assignment()
+            return self.engine.plan_view(
+                ev, self.build_view(), failed_dp_ranks=[d],
+                old_sample_rank=old_sample_rank, dp=len(st.dp_ranks))
 
     def recover_fail_stop(self, d: int, p: int, t_detect: float = 0.5,
                           ) -> Dict[str, float]:
@@ -548,11 +573,15 @@ class VirtualCluster:
             p, failed=[d], drain=drain)
 
         # --- communicator: in-place edit ---
-        comm_stats = self.comm.apply(GroupDelta.shrink([d * self.pp + p]),
-                                     "edit")
+        with span("recover.communicator"):
+            comm_stats = self.comm.apply(GroupDelta.shrink([d * self.pp + p]),
+                                         "edit")
 
         # --- live remap of stage p's optimizer state ---
-        t_remap, remap_plan = self._live_remap_stage(p, failed=[d])
+        with span("recover.remap") as sp:
+            t_remap, remap_plan = self._live_remap_stage(p, failed=[d])
+            # state that changes rank: plan intervals count fp32 elements
+            sp.set_metadata(nbytes=remap_plan.total_bytes * ADAM_STATE_BYTES)
 
         # --- layer migrations (graph plan) ---
         t_migr = 0.0
@@ -629,20 +658,24 @@ class VirtualCluster:
         pool = self.snapshots[p]
         if not pool.integrity:
             return 0.0, 0
-        t_verify, degraded = 0.0, 0
+        t_verify, degraded, nbytes = 0.0, 0, 0
         old_ranks = list(st.dp_ranks)
-        for f in failed:
-            j = old_ranks.index(f)
-            if pool.host[pool.holder_of(j)] is None:
-                continue    # holder dead: remap skips this source anyway
-            t_verify += pool.verify_cost_seconds(j)
-            tier, _ = pool.verify_and_repair(
-                j,
-                device_state=st.shard(f) if drain else None,
-                master_fallback=None if drain else
-                (lambda jj=j: self._master_shard_from_params(p, jj)))
-            if tier == "rebuilt":
-                degraded += 1
+        with span("recover.verify") as sp:
+            for f in failed:
+                j = old_ranks.index(f)
+                held = pool.host[pool.holder_of(j)]
+                if held is None:
+                    continue    # holder dead: remap skips this source anyway
+                t_verify += pool.verify_cost_seconds(j)
+                nbytes += sum(v.nbytes for v in held.values())
+                tier, _ = pool.verify_and_repair(
+                    j,
+                    device_state=st.shard(f) if drain else None,
+                    master_fallback=None if drain else
+                    (lambda jj=j: self._master_shard_from_params(p, jj)))
+                if tier == "rebuilt":
+                    degraded += 1
+            sp.set_metadata(nbytes=nbytes)
         return t_verify, degraded
 
     def _master_shard_from_params(self, p: int, j: int) -> np.ndarray:
@@ -759,13 +792,14 @@ class VirtualCluster:
         return out
 
     def _apply_dataflow(self):
-        # width of the narrowest stage defines surviving DP for data entry
-        widths = [int(self.alive[:, p].sum()) for p in range(self.pp)]
-        new_dp = max(min(widths), 1)
         from .planners.dataflow import plan_dataflow
-        df = plan_dataflow(self.global_batch, self.num_micro, new_dp)
-        self.per_rank_mbs = list(df.micro_batch_sizes)
-        self.grad_weights = list(df.grad_weights)
+        with span("recover.dataflow"):
+            # width of the narrowest stage defines surviving DP for data entry
+            widths = [int(self.alive[:, p].sum()) for p in range(self.pp)]
+            new_dp = max(min(widths), 1)
+            df = plan_dataflow(self.global_batch, self.num_micro, new_dp)
+            self.per_rank_mbs = list(df.micro_batch_sizes)
+            self.grad_weights = list(df.grad_weights)
 
     def _live_remap_stage(self, p: int, failed: List[int],
                           ) -> Tuple[float, RemapPlan]:
@@ -858,50 +892,57 @@ class VirtualCluster:
         gather per component per affected stage."""
         if not self.fast_path:
             return legacy.apply_migrations(self, moves, new_ranges)
-        total_stall = 0.0
-        # compute per-move timing with the migration model
-        step_window = self.simulate_step_time()
-        for (lid, src, dst) in moves:
-            st_src = self.stages[src]
-            pbytes = int(self.seg.param_bytes[lid])
-            obytes = int(self.seg.opt_bytes[lid])
-            spec = MigrationSpec((lid,), src, dst, pbytes, obytes,
-                                 dp=len(st_src.dp_ranks),
-                                 zero_layout=self.zero_layout,
-                                 blocking=not self.non_blocking_migration)
-            timing = migration_timing(spec, self.hw.link_bw, step_window)
-            total_stall += timing.stall_seconds
-        old_entries = {p: list(self.stages[p].entries) for p in range(self.pp)}
-        self.layer_assignment = list(new_ranges)
-        new_entries = {p: self._stage_entries(p) for p in range(self.pp)}
-        affected = [p for p in range(self.pp)
-                    if old_entries[p] != new_entries[p]]
-        # batch-slice the moving/retained entry state out of affected stages
-        entry_state: Dict[int, Dict[str, np.ndarray]] = {}
-        for p in affected:
-            st = self.stages[p]
-            tbl = st.table
-            for comp in COMPONENTS:
-                fullc = st.full(comp)
-                for pos, e in enumerate(st.entries):
-                    s_, e_ = tbl.layer_interval(pos)
-                    entry_state.setdefault(e, {})[comp] = fullc[s_:e_]
-        for p in affected:
-            survivors = list(self.stages[p].dp_ranks)
-            entries = new_entries[p]
-            sizes = [entry_state[e]["master"].size for e in entries]
-            full_by_comp = {
-                c: (np.concatenate([entry_state[e][c] for e in entries])
-                    if entries else np.zeros(0, np.float32))
-                for c in COMPONENTS}
-            new_st = StageState.from_full(entries, sizes, self.zero_layout,
-                                          survivors, full_by_comp)
-            self.stages[p] = new_st
-            self.snapshots[p] = SnapshotPool(len(survivors), self.adam,
-                                             batched=True)
-            if self.snapshot_enabled:
-                self.snapshots[p].bootstrap(
-                    self.step_count, [new_st.shard(r) for r in survivors])
+        with span("recover.migration") as sp:
+            total_stall = 0.0
+            # compute per-move timing with the migration model
+            step_window = self.simulate_step_time()
+            for (lid, src, dst) in moves:
+                st_src = self.stages[src]
+                pbytes = int(self.seg.param_bytes[lid])
+                obytes = int(self.seg.opt_bytes[lid])
+                spec = MigrationSpec((lid,), src, dst, pbytes, obytes,
+                                     dp=len(st_src.dp_ranks),
+                                     zero_layout=self.zero_layout,
+                                     blocking=not self.non_blocking_migration)
+                timing = migration_timing(spec, self.hw.link_bw, step_window)
+                total_stall += timing.stall_seconds
+            old_entries = {p: list(self.stages[p].entries)
+                           for p in range(self.pp)}
+            self.layer_assignment = list(new_ranges)
+            new_entries = {p: self._stage_entries(p) for p in range(self.pp)}
+            affected = [p for p in range(self.pp)
+                        if old_entries[p] != new_entries[p]]
+            # batch-slice the moving/retained entry state out of affected
+            # stages
+            entry_state: Dict[int, Dict[str, np.ndarray]] = {}
+            for p in affected:
+                st = self.stages[p]
+                tbl = st.table
+                for comp in COMPONENTS:
+                    fullc = st.full(comp)
+                    for pos, e in enumerate(st.entries):
+                        s_, e_ = tbl.layer_interval(pos)
+                        entry_state.setdefault(e, {})[comp] = fullc[s_:e_]
+            sp.set_metadata(nbytes=sum(entry_state[lid][c].nbytes
+                                       for lid, _, _ in moves
+                                       for c in COMPONENTS))
+            for p in affected:
+                survivors = list(self.stages[p].dp_ranks)
+                entries = new_entries[p]
+                sizes = [entry_state[e]["master"].size for e in entries]
+                full_by_comp = {
+                    c: (np.concatenate([entry_state[e][c] for e in entries])
+                        if entries else np.zeros(0, np.float32))
+                    for c in COMPONENTS}
+                new_st = StageState.from_full(entries, sizes,
+                                              self.zero_layout, survivors,
+                                              full_by_comp)
+                self.stages[p] = new_st
+                self.snapshots[p] = SnapshotPool(len(survivors), self.adam,
+                                                 batched=True)
+                if self.snapshot_enabled:
+                    self.snapshots[p].bootstrap(
+                        self.step_count, [new_st.shard(r) for r in survivors])
         return total_stall
 
     def _entry_from_stage(self, e: int) -> Dict[str, np.ndarray]:

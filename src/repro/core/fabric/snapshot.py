@@ -9,7 +9,8 @@ iteration (Fig. 6b timeline).
 Here "device" arrays are jnp, "host" buffers are numpy; the host-side Adam
 update is executed with the same math as the device (optim.adam), so after
 each step O_i^host == O_{(i+1)%n}^device bit-for-bit — which Live Remap
-relies on for integrity.  Timeline accounting feeds Table 3.
+relies on for integrity.  The host work is timed by the program spans
+``repro.snapshot.adam`` and ``repro.snapshot.crc`` (``repro/spans.py``).
 
 The default (batched) fast path concatenates every rank's gradient shard and
 host state into one flat vector per component and runs ONE host Adam update
@@ -28,6 +29,7 @@ import numpy as np
 
 from repro.optim.adam import (AdamConfig, adam_update_flat,
                               adam_update_flat_np)
+from repro.spans import span
 
 from ..statespace import COMPONENTS as _COMPONENTS
 
@@ -44,8 +46,6 @@ class SnapshotStats:
     step: int
     grad_bytes_sent: int
     state_bytes_equiv: int       # what shipping full Adam state would cost
-    host_update_seconds: float   # modeled host-side work (overlapped)
-    d2d_seconds: float           # modeled transfer (overlapped with Step/AG)
 
 
 class SnapshotPool:
@@ -58,13 +58,10 @@ class SnapshotPool:
     integrity goal is optimizer-semantics preservation, which holds)."""
 
     def __init__(self, n: int, adam_cfg: Optional[AdamConfig] = None,
-                 d2d_bw: float = 25e9, host_flops: float = 5e10,
                  compress: str = "none", batched: bool = True,
                  integrity: bool = True):
         self.n = n
         self.adam = adam_cfg or AdamConfig()
-        self.d2d_bw = d2d_bw
-        self.host_flops = host_flops
         assert compress in ("none", "bf16")
         self.compress = compress
         self.batched = batched
@@ -75,7 +72,6 @@ class SnapshotPool:
         # ONE vectorized call with no per-rank splitting.
         self.host: List[Optional[Dict[str, np.ndarray]]] = [None] * n
         self.snap_step: List[int] = [-1] * n
-        self.stats: List[SnapshotStats] = []
         self._cat: Optional[Dict[str, np.ndarray]] = None
         self._offs: Optional[np.ndarray] = None
         # crc[i][c] = CRC32 of holder i's copy of component c, stamped at
@@ -133,36 +129,37 @@ class SnapshotPool:
             return self._snapshot_step_loop(step, grad_shards, opt_step)
         # batched fast path: one concatenated compression + host-Adam update
         # covering every holder's snapshot (elementwise == the per-rank loop)
-        self._ensure_cat()
-        gs = [np.asarray(grad_shards[self.backup_rank(i)], dtype=np.float32)
-              for i in range(self.n)]
-        gcat = np.concatenate(gs) if gs else np.zeros(0, np.float32)
-        if self.compress == "bf16":
-            gcat = np.asarray(jnp.asarray(gcat).astype(jnp.bfloat16)
-                              .astype(jnp.float32))
-            total_grad_bytes = gcat.size * 2        # bf16 on the wire
-        else:
-            total_grad_bytes = int(gcat.nbytes)
-        self._cat = adam_update_flat_np(gcat, self._cat, opt_step, self.adam)
-        self._refresh_views()
+        with span("snapshot.adam") as sp:
+            self._ensure_cat()
+            gs = [np.asarray(grad_shards[self.backup_rank(i)],
+                             dtype=np.float32)
+                  for i in range(self.n)]
+            gcat = np.concatenate(gs) if gs else np.zeros(0, np.float32)
+            sp.set_metadata(elements=gcat.size)
+            if self.compress == "bf16":
+                gcat = np.asarray(jnp.asarray(gcat).astype(jnp.bfloat16)
+                                  .astype(jnp.float32))
+                total_grad_bytes = gcat.size * 2        # bf16 on the wire
+            else:
+                total_grad_bytes = int(gcat.nbytes)
+            self._cat = adam_update_flat_np(gcat, self._cat, opt_step,
+                                            self.adam)
+            self._refresh_views()
         for i in range(self.n):
             self.snap_step[i] = step
-        self._stamp_all()
-        stats = SnapshotStats(
+        with span("snapshot.crc",
+                  nbytes=sum(v.nbytes for v in self._cat.values())
+                  if self.integrity else 0):
+            self._stamp_all()
+        return SnapshotStats(
             step=step,
             grad_bytes_sent=total_grad_bytes,
-            state_bytes_equiv=total_grad_bytes // GRAD_BYTES * ADAM_STATE_BYTES,
-            host_update_seconds=gcat.size * 12 / self.host_flops,
-            d2d_seconds=total_grad_bytes / self.d2d_bw,
-        )
-        self.stats.append(stats)
-        return stats
+            state_bytes_equiv=total_grad_bytes // GRAD_BYTES * ADAM_STATE_BYTES)
 
     def _snapshot_step_loop(self, step: int, grad_shards: List[np.ndarray],
                             opt_step: int) -> SnapshotStats:
         """Seed per-rank loop (benchmark baseline; imports hoisted)."""
         total_grad_bytes = 0
-        host_flops = 0
         for i in range(self.n):
             j = self.backup_rank(i)
             g = np.asarray(grad_shards[j], dtype=np.float32)
@@ -178,18 +175,12 @@ class SnapshotPool:
                 jnp.asarray(g), {k: jnp.asarray(v) for k, v in st.items()},
                 opt_step, self.adam)
             self.host[i] = {k: np.asarray(v) for k, v in new_st.items()}
-            host_flops += g.size * 12     # ~12 flops/element Adam
             self.snap_step[i] = step
         self._stamp_all()
-        stats = SnapshotStats(
+        return SnapshotStats(
             step=step,
             grad_bytes_sent=total_grad_bytes,
-            state_bytes_equiv=total_grad_bytes // GRAD_BYTES * ADAM_STATE_BYTES,
-            host_update_seconds=host_flops / self.host_flops,
-            d2d_seconds=total_grad_bytes / self.d2d_bw,
-        )
-        self.stats.append(stats)
-        return stats
+            state_bytes_equiv=total_grad_bytes // GRAD_BYTES * ADAM_STATE_BYTES)
 
     def lose_rank(self, i: int):
         """Simulate fail-stop of worker i: its host snapshots die with it."""

@@ -114,4 +114,5 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

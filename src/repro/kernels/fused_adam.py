@@ -82,6 +82,7 @@ def fused_adam_kernel(grad, master, mu, nu, *, b1: float, b2: float,
         out_specs=[spec, spec, spec],
         out_shape=[shape, shape, shape],
         interpret=interpret,
+        name="fused_adam",
     )(g2, m2, mu2, nu2)
 
     def unprep(v):

@@ -50,6 +50,7 @@ def rmsnorm_kernel(x, scale, *, eps: float = 1e-5, interpret: bool):
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale.astype(jnp.float32))
     if pad:
         out = out[:rows]

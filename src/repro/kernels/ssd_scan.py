@@ -122,5 +122,6 @@ def ssd_scan_kernel(x, dt, A, Bh, Ch, *, chunk: int = 256, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(xdt, dA[..., None], dA.reshape(b, h, nc, 1, chunk), Bt, Ct)
     return jnp.moveaxis(y, 2, 1)               # [b,s,h,p]
