@@ -51,7 +51,7 @@ from repro.data.pipeline import GlobalBatchSampler, materialize_samples
 from repro.models import registry as R
 from repro.models.config import ModelConfig
 from repro.models.layers import RngCtx
-from repro.optim.adam import AdamConfig, adam_update_flat_np
+from repro.optim.adam import AdamConfig, adam_plan, adam_update_flat_np
 from repro.spans import span
 from . import legacy
 from .agent import Agent, Probe
@@ -392,7 +392,8 @@ class VirtualCluster:
             grad_shard_by_stage: List[List[np.ndarray]] = []
             off = 0
             for p, st in enumerate(self.stages):
-                with span("step.adam", elements=st.total, stage=p):
+                with span("step.adam", elements=st.total, stage=p,
+                          **adam_plan(st.total)):
                     # this stage's slice of the model-flat gradient, permuted
                     # to shard order with one fancy-index
                     gstage = gflat[off:off + st.total]

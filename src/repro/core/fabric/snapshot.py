@@ -27,7 +27,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.optim.adam import (AdamConfig, adam_update_flat,
+from repro.optim.adam import (AdamConfig, adam_plan, adam_update_flat,
                               adam_update_flat_np)
 from repro.spans import span
 
@@ -111,9 +111,6 @@ class SnapshotPool:
                                          for i in range(self.n)])
                          if self.n else np.zeros(0, np.float32))
                      for c in _COMPONENTS}
-        self._refresh_views()
-
-    def _refresh_views(self):
         for i in range(self.n):
             s, e = int(self._offs[i]), int(self._offs[i + 1])
             self.host[i] = {c: self._cat[c][s:e] for c in _COMPONENTS}
@@ -135,16 +132,16 @@ class SnapshotPool:
                              dtype=np.float32)
                   for i in range(self.n)]
             gcat = np.concatenate(gs) if gs else np.zeros(0, np.float32)
-            sp.set_metadata(elements=gcat.size)
+            sp.set_metadata(elements=gcat.size, **adam_plan(gcat.size))
             if self.compress == "bf16":
                 gcat = np.asarray(jnp.asarray(gcat).astype(jnp.bfloat16)
                                   .astype(jnp.float32))
                 total_grad_bytes = gcat.size * 2        # bf16 on the wire
             else:
                 total_grad_bytes = int(gcat.nbytes)
-            self._cat = adam_update_flat_np(gcat, self._cat, opt_step,
-                                            self.adam)
-            self._refresh_views()
+            # in place: host[i] stay views of the updated buffers
+            adam_update_flat_np(gcat, self._cat, opt_step, self.adam,
+                                out=self._cat)
         for i in range(self.n):
             self.snap_step[i] = step
         with span("snapshot.crc",

@@ -11,7 +11,9 @@ flattened per-layer vectors (its ZeRO-1 shards).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, NamedTuple, Tuple
+import os
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,7 +92,67 @@ def adam_update_flat(grad_vec, st, step: int, cfg: AdamConfig):
     return master, {"master": master, "mu": mu, "nu": nu}
 
 
-def adam_update_flat_np(grad_vec, st, step: int, cfg: AdamConfig):
+#: elements of one block of the host AdamW: a block's fifteen elementwise
+#: passes run over data the previous pass left in cache, and each pass is
+#: long enough that the threads seldom wait for the GIL, which numpy holds
+#: between passes.  The best of a sweep on a TPU v5e host
+#: (``benchmarks/host_adam.py``, PERF.md): smaller blocks wait on the GIL
+BLOCK = 1 << 20
+#: below this many elements the whole vector is one block on the calling
+#: thread (the CPU tests' models); at and above it the blocks are shared
+#: among the pool's threads
+THRESHOLD = 1 << 20
+#: worker threads: every core the process may run on, up to 8, beyond which
+#: the same sweep gained nothing
+THREADS = max(1, min(len(os.sched_getaffinity(0)), 8))
+_pool: Optional[ThreadPoolExecutor] = None
+
+
+def adam_plan(n: int) -> Dict[str, int]:
+    """``{"blocks", "threads"}`` the host AdamW uses over ``n`` elements."""
+    if n < THRESHOLD:
+        return {"blocks": 1, "threads": 1}
+    blocks = max(1, -(-n // BLOCK))
+    return {"blocks": blocks, "threads": min(THREADS, blocks)}
+
+
+def _adam_blocks(g, st, out, lo: int, hi: int, block: int, step: int,
+                 cfg: AdamConfig):
+    """The op sequence of :func:`adam_update_flat` over elements
+    ``[lo, hi)``, ``block`` elements at a time, into ``out``.  ``out`` may
+    be ``st`` itself: every op is elementwise and a block's old master is
+    read before its new one is written."""
+    b1, b2 = np.float32(cfg.b1), np.float32(cfg.b2)
+    c1, c2 = np.float32(1 - cfg.b1), np.float32(1 - cfg.b2)
+    b1t = np.float32(1.0 - cfg.b1 ** step)
+    b2t = np.float32(1.0 - cfg.b2 ** step)
+    eps, wd = np.float32(cfg.eps), np.float32(cfg.weight_decay)
+    lr = np.float32(cfg.lr)
+    t = np.empty(min(block, hi - lo), np.float32)
+    u = np.empty_like(t)
+    for a in range(lo, hi, block):
+        b = min(a + block, hi)
+        tb, ub, gb = t[:b - a], u[:b - a], g[a:b]
+        master, mu, nu = out["master"][a:b], out["mu"][a:b], out["nu"][a:b]
+        np.multiply(st["mu"][a:b], b1, out=mu)
+        np.multiply(gb, c1, out=tb)
+        mu += tb
+        np.multiply(st["nu"][a:b], b2, out=nu)
+        np.multiply(gb, c2, out=tb)
+        tb *= gb
+        nu += tb
+        np.divide(mu, b1t, out=ub)
+        np.divide(nu, b2t, out=tb)
+        np.sqrt(tb, out=tb)
+        tb += eps
+        ub /= tb
+        np.multiply(st["master"][a:b], wd, out=tb)
+        ub += tb
+        ub *= lr
+        np.subtract(st["master"][a:b], ub, out=master)
+
+
+def adam_update_flat_np(grad_vec, st, step: int, cfg: AdamConfig, out=None):
     """Host-side (numpy) mirror of :func:`adam_update_flat`, bit-identical.
 
     IEEE basic ops (+, -, *, /, sqrt) are correctly rounded in both numpy
@@ -102,28 +164,34 @@ def adam_update_flat_np(grad_vec, st, step: int, cfg: AdamConfig):
     bit-identity to the eager path is checked by
     ``tests/test_zero_and_fabric.py``.
 
+    The flat vectors are updated in blocks of ``BLOCK`` elements, shared
+    among ``THREADS`` threads from ``THRESHOLD`` elements on (numpy's
+    ufuncs release the GIL); :func:`adam_plan` says how a size is split.
+    ``out``, a dict of ``master``/``mu``/``nu`` float32 arrays, receives the
+    new state, as numpy's ``out=`` does; it may be ``st`` itself (in place).
+    Without it the new state is in fresh arrays and ``st`` is left intact.
+
     Returns the new state dict {master, mu, nu} (f32 numpy arrays).
     """
-    g = np.asarray(grad_vec, dtype=np.float32)
-    b1t = np.float32(1.0 - cfg.b1 ** step)
-    b2t = np.float32(1.0 - cfg.b2 ** step)
-    # the op sequence of adam_update_flat, one rounding per op, written
-    # in place over three temporaries: a fresh array per op costs more in
-    # page faults than the arithmetic at a stage's size (~0.3 B elements)
-    mu = np.multiply(st["mu"], np.float32(cfg.b1))
-    t = np.multiply(g, np.float32(1 - cfg.b1))
-    mu += t
-    nu = np.multiply(st["nu"], np.float32(cfg.b2))
-    np.multiply(g, np.float32(1 - cfg.b2), out=t)
-    t *= g
-    nu += t
-    upd = np.divide(mu, b1t)
-    np.divide(nu, b2t, out=t)
-    np.sqrt(t, out=t)
-    t += np.float32(cfg.eps)
-    upd /= t
-    np.multiply(st["master"], np.float32(cfg.weight_decay), out=t)
-    upd += t
-    upd *= np.float32(cfg.lr)
-    master = np.subtract(st["master"], upd, out=upd)
-    return {"master": master, "mu": mu, "nu": nu}
+    global _pool
+    g = np.asarray(grad_vec, dtype=np.float32).reshape(-1)
+    st = {c: np.asarray(st[c], dtype=np.float32).reshape(-1)
+          for c in ("master", "mu", "nu")}
+    if out is None:
+        out = {c: np.empty_like(g) for c in st}
+    n = g.size
+    plan = adam_plan(n)
+    threads = plan["threads"]
+    if threads == 1:
+        _adam_blocks(g, st, out, 0, n, max(-(-n // plan["blocks"]), 1),
+                     step, cfg)
+    else:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(THREADS, "host-adam")
+        # each thread a contiguous run of whole blocks
+        cuts = [BLOCK * (plan["blocks"] * k // threads)
+                for k in range(threads)] + [n]
+        list(_pool.map(lambda k: _adam_blocks(g, st, out, cuts[k],
+                                              cuts[k + 1], BLOCK, step, cfg),
+                       range(threads)))
+    return {c: out[c] for c in ("master", "mu", "nu")}

@@ -121,6 +121,17 @@ def test_the_steps_and_their_counts(traced):
             == n_params * 12
 
 
+def test_adam_spans_carry_their_split(traced):
+    """Both host AdamW spans say how the update was split; the tiny model's
+    stages take the single-block path on the calling thread."""
+    _, spans = traced
+    adam = [s["stats"] for s in spans
+            if s["name"] in ("repro.step.adam", "repro.snapshot.adam")]
+    assert len(adam) == 2 * 2 + 2 * 2       # two stages, two steps
+    for stats in adam:
+        assert (stats["blocks"], stats["threads"]) == (1, 1)
+
+
 def test_recovery_moves_state(traced):
     _, spans = traced
     by = {s["name"]: s["stats"] for s in spans

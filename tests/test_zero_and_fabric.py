@@ -193,3 +193,110 @@ def test_host_adam_bit_identical_to_eager(step):
     for k in want:
         np.testing.assert_array_equal(got[k], np.asarray(want[k]))
         np.testing.assert_array_equal(state[k], before[k])   # input intact
+
+
+#: the blocked update at test scale: blocks of 64 elements, threads from 256
+SMALL_BLOCK, SMALL_THRESHOLD, SMALL_THREADS = 64, 256, 3
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    from repro.optim import adam
+    monkeypatch.setattr(adam, "BLOCK", SMALL_BLOCK)
+    monkeypatch.setattr(adam, "THRESHOLD", SMALL_THRESHOLD)
+    monkeypatch.setattr(adam, "THREADS", SMALL_THREADS)
+    monkeypatch.setattr(adam, "_pool", None)
+    yield adam
+    if adam._pool is not None:
+        adam._pool.shutdown()
+
+
+def _adam_case(n, step):
+    rng = np.random.default_rng(n * 10 + step)
+    g = rng.standard_normal(n).astype(np.float32)
+    state = {"master": rng.standard_normal(n).astype(np.float32),
+             "mu": (rng.standard_normal(n) * 0.01).astype(np.float32),
+             "nu": np.abs(rng.standard_normal(n) * 0.01).astype(np.float32)}
+    return g, state
+
+
+@pytest.mark.parametrize("in_place", [False, True], ids=["fresh", "in_place"])
+@pytest.mark.parametrize("step", [1, 7])
+@pytest.mark.parametrize("n, blocks, threads", [
+    (0, 1, 1),                                   # empty
+    (SMALL_THRESHOLD - 56, 1, 1),                # below the threshold
+    (8 * SMALL_BLOCK, 8, SMALL_THREADS),         # a multiple of the block
+    (8 * SMALL_BLOCK - 1, 8, SMALL_THREADS),
+    (8 * SMALL_BLOCK + 1, 9, SMALL_THREADS),
+    (13 * SMALL_BLOCK + 5, 14, SMALL_THREADS),   # several blocks a thread
+])
+def test_blocked_host_adam_bit_identical_to_eager(small_blocks, n, blocks,
+                                                  threads, step, in_place):
+    """Blocks and threads change no bit of any component; in place (``out``
+    the state itself) gives the same bits, and without ``out`` the input
+    stays intact."""
+    import jax.numpy as jnp
+    adam = small_blocks
+    assert adam.adam_plan(n) == {"blocks": blocks, "threads": threads}
+    g, state = _adam_case(n, step)
+    before = {k: v.copy() for k, v in state.items()}
+    _, want = adam_update_flat(jnp.asarray(g),
+                               {k: jnp.asarray(v) for k, v in state.items()},
+                               step, AdamConfig())
+    got = adam_update_flat_np(g, state, step, AdamConfig(),
+                              out=state if in_place else None)
+    for k in want:
+        np.testing.assert_array_equal(got[k], np.asarray(want[k]))
+        if in_place:
+            assert got[k] is state[k]
+        else:
+            np.testing.assert_array_equal(state[k], before[k])
+
+
+def test_host_adam_sweep_is_bit_identical(small_blocks, capsys):
+    """``benchmarks/host_adam.py``, the sweep that sets ``BLOCK`` and
+    ``THREADS``, runs every setting bit-identical to one block."""
+    from benchmarks import host_adam
+    host_adam.main(n=5 * SMALL_BLOCK + 3)
+    rows = [r for r in capsys.readouterr().out.splitlines()
+            if r.startswith("host_adam[")]
+    assert len(rows) > 2 * len(host_adam.BLOCKS)
+    assert all(r.endswith("bit_identical=True") for r in rows[1:])
+
+
+def test_in_place_snapshot_keeps_its_views(small_blocks):
+    """After in-place ``snapshot_step``s on the threaded path, ``host[i]``
+    are still views of the pool's buffers and hold the neighbour's updated
+    state; the write-time checksums hold, and a corruption after the step is
+    still caught."""
+    import jax.numpy as jnp
+    n, m = 3, 5 * SMALL_BLOCK + 7     # 3 x 327 elements: blocks over threads
+    assert small_blocks.adam_plan(n * m)["threads"] > 1
+    rng = np.random.default_rng(3)
+    adam = AdamConfig()
+    states = [{"master": rng.normal(size=m).astype(np.float32),
+               "mu": np.zeros(m, np.float32), "nu": np.zeros(m, np.float32)}
+              for _ in range(n)]
+    pool = SnapshotPool(n, adam)
+    pool.bootstrap(0, states)
+    for step in range(1, 4):
+        grads = [rng.normal(size=m).astype(np.float32) for _ in range(n)]
+        for j in range(n):
+            _, new = adam_update_flat(
+                jnp.asarray(grads[j]),
+                {k: jnp.asarray(v) for k, v in states[j].items()}, step, adam)
+            states[j] = {k: np.asarray(v) for k, v in new.items()}
+        cat = dict(pool._cat) if pool._cat is not None else None
+        pool.snapshot_step(step, grads, step)
+        if cat is not None:       # the step wrote into the same buffers
+            assert all(pool._cat[c] is cat[c] for c in cat)
+        for i in range(n):
+            for comp in ("master", "mu", "nu"):
+                view = pool.host[i][comp]
+                assert view.base is pool._cat[comp]
+                np.testing.assert_array_equal(
+                    view, states[pool.backup_rank(i)][comp])
+    assert all(pool.verify_shard(j) for j in range(n))
+    pool.corrupt_shard(1, "mu", index=11)
+    assert not pool.verify_shard(1)
+    assert pool.verify_shard(0) and pool.verify_shard(2)
