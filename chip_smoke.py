@@ -25,8 +25,9 @@ Phases (one chip):
    step 0 == a float32 reference within ``BF16_RTOL``, and Mosaic kernels in
    the compiled step program.
 
-With ``--chips 4`` only the sharded train step of ``launch/steps.py`` runs,
-on a (data=2, model=2) mesh, against the same step unsharded on device 0.
+With ``--chips 4`` only the sharded train step of ``launch/steps.py`` runs
+(``compile_sharded``), on a (data=2, model=2) mesh, against the same step
+compiled for device 0 alone.
 
 The last line of a passing run is ``{"ok": true, "device": {...}}``.
 """
@@ -244,28 +245,19 @@ def elastic_phase(cfg: ModelConfig, *, seed: int, seq: int = SEQ,
 # --chips 4: the sharded train step
 # ---------------------------------------------------------------------------
 def _train_losses(cell, cfg: ModelConfig, *, steps: int, seed: int,
-                  mesh=None) -> Dict:
-    """``steps`` steps of the train cell: sharded by its pspecs over
-    ``mesh``, or, when None, unsharded on the default device."""
+                  mesh) -> Dict:
+    """``steps`` steps of the train cell compiled over ``mesh`` by
+    ``launch/steps.py:compile_sharded``, its pspecs placing the state."""
+    from repro.launch.steps import compile_sharded
     from repro.optim.adam import AdamConfig, init_opt_state
-    from repro.parallel.sharding import to_shardings
     batch, seq = cell.arg_shapes[2]["tokens"].shape
-    if mesh is None:
-        place = [{} for _ in cell.arg_pspecs]
-        step_kw = {}
-        devices = [jax.devices()[0]]
-    else:
-        place = [{"out_shardings": to_shardings(mesh, p)}
-                 for p in cell.arg_pspecs]
-        step_kw = {"in_shardings": tuple(p["out_shardings"] for p in place),
-                   "out_shardings": to_shardings(mesh, cell.out_pspecs)}
-        devices = list(mesh.devices.flat)
-    step_fn = jax.jit(cell.fn, donate_argnums=cell.donate, **step_kw)
-    params = jax.jit(lambda k: R.init_model(k, cfg), **place[0])(
+    step_fn = compile_sharded(cell, mesh)
+    params_at, opt_at, batch_at = step_fn.in_shardings
+    params = jax.jit(lambda k: R.init_model(k, cfg), out_shardings=params_at)(
         jax.random.key(seed))
-    opt = jax.jit(lambda p: init_opt_state(p, AdamConfig()), **place[1])(
-        params)
-    put = jax.jit(lambda b: b, **place[2])
+    opt = jax.jit(lambda p: init_opt_state(p, AdamConfig()),
+                  out_shardings=opt_at)(params)
+    put = jax.jit(lambda b: b, out_shardings=batch_at)
     sampler = GlobalBatchSampler(batch, seed)
     losses, step_s = [], []
     for step in range(steps):
@@ -276,6 +268,7 @@ def _train_losses(cell, cfg: ModelConfig, *, steps: int, seed: int,
         params, opt, loss = step_fn(params, opt, b)
         losses.append(float(loss))
         step_s.append(time.perf_counter() - t0)
+    devices = list(mesh.devices.flat)
     in_use = [device_bytes(d, "bytes_in_use") for d in devices]
     return {"losses": losses, "step_s": step_s, "bytes_in_use": in_use,
             "devices": [str(d) for d in devices]}
@@ -291,14 +284,15 @@ def sharded_phase(cfg: ModelConfig, *, seed: int, seq: int = SEQ,
     say(f"[sharded] train cell of launch/steps.py, parallel/sharding.py "
         f"pspecs, mesh {dict(mesh.shape)}, batch {batch} x seq {seq}, "
         f"remat on")
+    one_chip = make_mesh((1, 1), ("data", "model"), jax.devices()[:1])
     runs = {}
-    for name, m in (("sharded", mesh), ("unsharded", None)):
+    for name, m in (("sharded", mesh), ("unsharded", one_chip)):
         t0 = time.perf_counter()
         runs[name] = r = _train_losses(cell, cfg, steps=steps, seed=seed,
                                        mesh=m)
         gc.collect()
         say(f"[{name}] losses={r['losses']} step wall s={r['step_s']} "
-            f"(the first includes compile; each ends in float(loss)); "
+            f"(compiled ahead; each ends in float(loss)); "
             f"{time.perf_counter() - t0:.1f} s in all")
         for d, nbytes in zip(r["devices"], r["bytes_in_use"]):
             say(f"[{name}] {d}: bytes_in_use={nbytes} (state live)")

@@ -16,6 +16,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes, axis_types=types)
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes, devices=None):
+    """A mesh of ``shape`` over ``devices`` (default: all of them)."""
     types = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types,
+                         devices=devices)

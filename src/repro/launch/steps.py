@@ -18,9 +18,11 @@ import jax.numpy as jnp
 from repro.models import registry as R
 from repro.models import transformer as T
 from repro.models import encdec as E
+from repro.launch.hlo_analysis import executed_collective_bytes
 from repro.models.config import ModelConfig
 from repro.optim.adam import AdamConfig, adam_update, init_opt_state, opt_state_shapes
 from repro.parallel import sharding as S
+from repro.spans import span
 
 
 @dataclasses.dataclass
@@ -155,6 +157,47 @@ def _encdec_serving_cell(cfg, kind, seq, batch, mesh, params_sh, pspec_params):
                  S._spec(mesh, (batch, 1), S.dp_axes(mesh), None),
                  enc_spec, P()),
                 (P(), pspec_caches), donate=(1,))
+
+
+class ShardedStep:
+    """A train cell compiled over a mesh (:func:`compile_sharded`).
+
+    Called as the cell's ``fn`` (params, opt_state, batch), each call under
+    ``span("sharded.step", step=i, collective_bytes=...)``; the call only
+    dispatches, so the span ends before the device does.  ``in_shardings``
+    place the arguments: weights made under ``jax.jit(...,
+    out_shardings=in_shardings[0])`` are never whole on one chip."""
+
+    def __init__(self, compiled, in_shardings, collective_bytes: int):
+        self.compiled = compiled
+        self.in_shardings = in_shardings
+        self.collective_bytes = collective_bytes
+        self.calls = 0
+
+    def __call__(self, *args):
+        with span("sharded.step", step=self.calls,
+                  collective_bytes=self.collective_bytes):
+            self.calls += 1
+            return self.compiled(*args)
+
+
+def compile_sharded(cell: Cell, mesh) -> ShardedStep:
+    """Place and compile a train ``cell`` over ``mesh``: its pspecs become
+    the shardings of its arguments and results, its state is donated, and
+    it is lowered from its shapes and compiled under
+    ``span("compile.sharded", collective_bytes=...)``.  ``collective_bytes``
+    is ``hlo_analysis.executed_collective_bytes`` of the compiled (per-chip)
+    program, summed over kinds: the operand bytes of the collectives one
+    chip starts in a step, a layer loop's once a layer."""
+    ins = tuple(S.to_shardings(mesh, p) for p in cell.arg_pspecs)
+    fn = jax.jit(cell.fn, in_shardings=ins,
+                 out_shardings=S.to_shardings(mesh, cell.out_pspecs),
+                 donate_argnums=cell.donate)
+    with span("compile.sharded") as sp:
+        compiled = fn.lower(*cell.arg_shapes).compile()
+        nbytes = int(executed_collective_bytes(compiled.as_text())["total"])
+        sp.set_metadata(collective_bytes=nbytes)
+    return ShardedStep(compiled, ins, nbytes)
 
 
 def _opt_pspecs_like(params_sh, pspec_params, opt_sh):

@@ -37,6 +37,7 @@ class ModelConfig:
     dtype: str = "bfloat16"
     dropout_rate: float = 0.0
     tie_embeddings: bool = False
+    qkv_bias: bool = False           # bias on the q/k/v projections (Qwen2)
 
     # --- MoE ---
     num_experts: int = 0
@@ -207,6 +208,8 @@ class ModelConfig:
                 n += d * self.num_heads * hd          # q
                 n += 2 * d * self.num_kv_heads * hd   # k, v
                 n += self.num_heads * hd * d          # o
+                if self.qkv_bias:
+                    n += (self.num_heads + 2 * self.num_kv_heads) * hd
         if blk in (MAMBA, MAMBA_MOE):
             di, ds, ng = self.d_inner, self.ssm_state, self.ssm_ngroups
             n += d * (2 * di + 2 * ng * ds + self.ssm_heads)  # in_proj

@@ -107,9 +107,10 @@ def decode(params, cfg: ModelConfig, tokens, enc_out,
         # _sdpa gate checks.
         h = L.rmsnorm(blkp["lnx"], x, cfg.norm_eps, use_pallas=use_pallas)
         Hh, hd = cfg.num_heads, cfg.head_dim
-        q = (h @ blkp["xattn"]["wq"]).reshape(B, S, Hh, hd)
-        k = (enc_out @ blkp["xattn"]["wk"]).reshape(B, T, cfg.num_kv_heads, hd)
-        v = (enc_out @ blkp["xattn"]["wv"]).reshape(B, T, cfg.num_kv_heads, hd)
+        xa = blkp["xattn"]
+        q = L._project(xa, cfg, h, "q").reshape(B, S, Hh, hd)
+        k = L._project(xa, cfg, enc_out, "k").reshape(B, T, cfg.num_kv_heads, hd)
+        v = L._project(xa, cfg, enc_out, "v").reshape(B, T, cfg.num_kv_heads, hd)
         a = L._sdpa(q, k, v, causal=False, use_pallas=use_pallas)
         x = x + a.reshape(B, S, Hh * hd) @ blkp["xattn"]["wo"]
         h = L.rmsnorm(blkp["ln2"], x, cfg.norm_eps, use_pallas=use_pallas)

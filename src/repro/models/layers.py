@@ -8,7 +8,8 @@ Design notes
   The mask depends only on (step, layer, sample) identity — never on which rank
   or micro-batch slot computes it — so any elastic re-partitioning reproduces
   bit-identical randomness.  See core/planners/rng.py.
-* Attention supports GQA (kv-head broadcast) and MLA (latent KV, deepseek-v3).
+* Attention supports GQA (kv-head broadcast), with a bias on the q/k/v
+  projections where ``cfg.qkv_bias`` (Qwen2), and MLA (latent KV, deepseek-v3).
 * KV caches are explicit pytrees so serve_step can be jitted/lowered.
 """
 from __future__ import annotations
@@ -113,12 +114,16 @@ def init_attention(key, cfg: ModelConfig) -> Dict[str, Any]:
     d, H, Hkv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     dt = cfg.jnp_dtype
     ks = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": _dense(ks[0], (d, H * hd), dt),
         "wk": _dense(ks[1], (d, Hkv * hd), dt),
         "wv": _dense(ks[2], (d, Hkv * hd), dt),
         "wo": _dense(ks[3], (H * hd, d), dt),
     }
+    if cfg.qkv_bias:
+        p.update(bq=jnp.zeros((H * hd,), dt), bk=jnp.zeros((Hkv * hd,), dt),
+                 bv=jnp.zeros((Hkv * hd,), dt))
+    return p
 
 
 def _sdpa_chunked(q, k, v, causal: bool, chunk_q: int = 512,
@@ -219,6 +224,13 @@ def _sdpa(q, k, v, causal: bool, q_offset=None, use_pallas: bool = False):
     return out.reshape(B, S, H, v.shape[-1])
 
 
+def _project(params, cfg: ModelConfig, x, which: str):
+    """x @ w{which}, plus b{which} where the config has q/k/v bias: added
+    before RoPE, so every path (train, prefill, decode, chunked) has it."""
+    y = x @ params["w" + which]
+    return y + params["b" + which] if cfg.qkv_bias else y
+
+
 def apply_attention(params, cfg: ModelConfig, x, positions,
                     kv_cache: Optional[Dict] = None, cache_index=None,
                     causal: bool = True, use_pallas: bool = False,
@@ -226,9 +238,9 @@ def apply_attention(params, cfg: ModelConfig, x, positions,
     """x: [B,S,d].  If kv_cache given, append k/v at cache_index (decode)."""
     B, S, d = x.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = (x @ params["wq"]).reshape(B, S, H, hd)
-    k = (x @ params["wk"]).reshape(B, S, Hkv, hd)
-    v = (x @ params["wv"]).reshape(B, S, Hkv, hd)
+    q = _project(params, cfg, x, "q").reshape(B, S, H, hd)
+    k = _project(params, cfg, x, "k").reshape(B, S, Hkv, hd)
+    v = _project(params, cfg, x, "v").reshape(B, S, Hkv, hd)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     new_cache = None

@@ -104,6 +104,8 @@ def param_pspecs(cfg: ModelConfig, mesh: Mesh, params_shapes) -> Any:
             if len(core) == 3:           # MoE [E, ff, d]
                 return out("model", None, DP)
             return out("model", DP)
+        if name in ("bq", "bk", "bv"):   # q/k/v bias: as wq/wk/wv's out-dim
+            return out("model")
         if name == "w":                  # lm head [d, V]
             return out(DP, "model")
         if name == "router":

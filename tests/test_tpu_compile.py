@@ -97,3 +97,21 @@ def test_ssd_scan_corpus_chunks(mosaic, chunk):
         mosaic((b, s, h, p)), mosaic((b, s, h)), mosaic((h,)),
         mosaic((b, s, g, n)), mosaic((b, s, g, n)))
     assert "tpu_custom_call" in text
+
+
+def test_sharded_step_collective_bytes_match_the_recorded_trace(topo):
+    """The sharded train step recorded on a 2x2 v5e host
+    (``chipbench/tests/record_collectives.py``), compiled here by
+    ``compile_sharded`` for the described 2x2: the ``collective_bytes`` its
+    spans carry (a step's, its layer loop's trips read from the loop's
+    condition) are the bytes every chip's op line started a step in the
+    recording (``chipbench/tests/test_collectives.py``)."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from chipbench.tests.record_collectives import program
+    from repro.launch.steps import compile_sharded
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    _, cell = program(mesh)
+    assert compile_sharded(cell, mesh).collective_bytes == 45_230_576
