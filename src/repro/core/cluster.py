@@ -27,7 +27,8 @@ is exactly the paper's; see DESIGN.md §3.
 Two step/recovery implementations share this state:
 
 * the **fast path** (default) — one jitted, ``vmap``-batched call over the
-  step's micro-batches with a single ``device_get``, one fused host-side
+  step's micro-batches that weights and sums their gradients on the device,
+  with a single ``device_get`` of the sum, one fused host-side
   Adam update per stage, indexed-scatter parameter write-back, and batched
   recovery that only rebuilds the stages an event actually touches;
 * the **seed path** (``fast_path=False``, ``core/legacy.py``) — the original
@@ -265,26 +266,41 @@ class VirtualCluster:
 
     def _batched_grad_fn(self, batch_size: int, n_items: int):
         """One jitted call over ``n_items`` stacked micro-batches of
-        ``batch_size``: per-item loss + flat gradient, no host sync inside
-        the step.  ``vmap`` batches the independent per-item grads (a
-        different XLA program from the per-item jit calls, so it agrees with
-        them to float32 round-off, not bit for bit; a ``lax.scan`` over items
-        is ~1.5x slower on CPU)."""
+        ``batch_size``: the per-item losses, and the items' gradients
+        weighted by ``weights`` (f32[n_items]) and summed into one flat
+        float32 gradient, so the host fetches one vector however many items
+        the bucket holds.  ``vmap`` batches the independent per-item grads
+        (a different XLA program from the per-item jit calls, so it agrees
+        with them to float32 round-off, not bit for bit; a ``lax.scan`` over
+        items is ~1.5x slower on CPU).  The weights are an argument, not a
+        traced constant, so a recovery that reweights the ranks runs the
+        compiled program it has."""
         key = (batch_size, n_items)
         fn = self._scan_grad_cache.get(key)
         if fn is None:
             grad_one = jax.value_and_grad(self._loss_fn, argnums=(0, 1, 2))
 
-            def batched(stem, layers, head, toks, labs, base_key, step, sids):
+            def batched(stem, layers, head, toks, labs, base_key, step, sids,
+                        weights):
                 # fold_in inside the jit: integer PRNG ops, bit-identical to
                 # the eager fold, and one less host dispatch per step
                 step_key = jax.random.fold_in(base_key, step)
 
                 def one(tok, lab, sid):
-                    loss, grads = grad_one(stem, layers, head, tok, lab,
-                                           step_key, sid)
-                    return loss, ravel_pytree(grads)[0]
-                return jax.vmap(one)(toks, labs, sids)
+                    return grad_one(stem, layers, head, tok, lab, step_key,
+                                    sid)
+
+                def fold(g):
+                    # [n_items, ...] -> [...], a left fold in item order in
+                    # float32: g[0]*w[0] + g[1]*w[1] + ...; per leaf, before
+                    # the ravel, so no stacked flat copy is made
+                    g = g.astype(jnp.float32)
+                    acc = g[0] * weights[0]
+                    for k in range(1, n_items):
+                        acc = acc + g[k] * weights[k]
+                    return acc
+                losses, grads = jax.vmap(one)(toks, labs, sids)
+                return losses, ravel_pytree(jax.tree.map(fold, grads))[0]
 
             fn = jax.jit(batched)
             self._scan_grad_cache[key] = fn
@@ -294,7 +310,9 @@ class VirtualCluster:
         """The step's micro-items ``(rank, sample ids)`` in the seed's
         (micro, rank) order, one ``(item indices, jitted fn, args)`` call
         per micro-batch size bucket (uneven after a failure), and the bytes
-        of tokens and sample ids uploaded for them."""
+        of tokens, sample ids and item weights uploaded for them.  Each
+        item's weight, ``grad_weights[rank] / num_micro``, is read here on
+        every call."""
         ids_by_rank = self.sampler.partition(step, self.per_rank_mbs,
                                              self.num_micro)
         items: List[Tuple[int, np.ndarray]] = []    # (rank, ids), seed order
@@ -320,11 +338,14 @@ class VirtualCluster:
                 sids = np.stack([np.arange(B, dtype=np.int32)
                                  + np.int32(items[k][0] * 100003)
                                  for k in idxs])
+            weights = np.asarray([self.grad_weights[items[k][0]]
+                                  / self.num_micro for k in idxs], np.float32)
             jt = jnp.asarray(toks)
             args = (self.stem, self.layer_params, self.head, jt, jt,
-                    self.base_key, np.uint32(step), jnp.asarray(sids))
+                    self.base_key, np.uint32(step), jnp.asarray(sids),
+                    jnp.asarray(weights))
             calls.append((idxs, self._batched_grad_fn(B, len(idxs)), args))
-            nbytes += toks.nbytes + sids.nbytes
+            nbytes += toks.nbytes + sids.nbytes + weights.nbytes
         return items, calls, nbytes
 
     def _micro_grads(self, step: int) -> Tuple[float, np.ndarray]:
@@ -332,40 +353,38 @@ class VirtualCluster:
         numerics of dataflow-resized hybrid-parallel training.
 
         Fast path: micro-batches are bucketed by size (uneven after a
-        failure), each bucket runs as ONE jitted vmap-batched call, and one
+        failure), each bucket runs as ONE jitted vmap-batched call that
+        weights and sums its items' gradients on the device, and one
         ``device_get`` per bucket (one per step in the common even-split
-        case) fetches all losses + flat per-item gradients, which then
-        accumulate host-side in the seed's exact (micro, rank) order.
-        Returns ``(total_loss, model-flat gradient)``.
+        case) fetches its losses and that one flat partial sum.  With one
+        bucket the partial is the step's gradient; with several the host
+        adds the partials in bucket order.  Returns ``(total_loss,
+        model-flat gradient)``.
         """
         with span("step.inputs") as sp:
             items, calls, nbytes = self._grad_calls(step)
             sp.set_metadata(nbytes=nbytes)
-        n = len(items)
-        loss_rows: List[Any] = [None] * n
-        flat_rows: List[Any] = [None] * n
+        loss_rows: List[Any] = [None] * len(items)
+        partials: List[np.ndarray] = []
         for idxs, fn, args in calls:
-            # one device_get per bucket (exactly one per step in the even-
-            # split common case) for all losses + flat grads together; the
-            # wait before it parts device time from the copy to the host
+            # the wait before the fetch parts device time from the copy to
+            # the host
             with span("step.device", items=len(idxs)):
                 out = jax.block_until_ready(fn(*args))
             with span("step.fetch", nbytes=sum(x.nbytes for x in out)):
-                losses, flats = jax.device_get(out)
+                losses, partial = jax.device_get(out)
             for i, k in enumerate(idxs):
                 loss_rows[k] = losses[i]
-                flat_rows[k] = flats[i]
-        # host-side weighted accumulation in the seed's (micro, rank) order;
-        # numpy f32 elementwise ops are bit-identical to the seed's eager
-        # per-leaf jnp ops (IEEE correctly-rounded either way)
-        acc = None
-        total_loss = 0.0
-        with span("step.accumulate", elements=sum(f.size for f in flat_rows)):
+            partials.append(partial)
+        with span("step.accumulate", device_items=len(items),
+                  elements=sum(p.size for p in partials[1:])):
+            acc = partials[0]
+            for partial in partials[1:]:
+                acc = acc + partial
+            total_loss = 0.0
             for k, (r, _ids) in enumerate(items):
-                w = self.grad_weights[r] / self.num_micro
-                gw = flat_rows[k] * np.float32(w)
-                acc = gw if acc is None else acc + gw
-                total_loss += float(loss_rows[k]) * w
+                total_loss += (float(loss_rows[k])
+                               * (self.grad_weights[r] / self.num_micro))
         return total_loss, acc
 
     def compile_step(self) -> List[Any]:

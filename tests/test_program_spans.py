@@ -42,20 +42,13 @@ def _profile_options():
     return opts
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    """(cluster, spans): each span a dict of its line, name, start, end
-    (ns) and stats, in the order of their start."""
-    cl = VirtualCluster(R.tiny_config("dense", num_layers=4), dp=2, pp=2,
-                        global_batch=4, num_micro=1, seq_len=16, seed=0)
-    d = tmp_path_factory.mktemp("trace")
+def _trace(d, work):
+    """The program's spans while ``work()`` runs under the profiler: each a
+    dict of its line, name, start, end (ns) and stats, in the order of
+    their start."""
     jax.profiler.start_trace(str(d), profiler_options=_profile_options())
     try:
-        cl.compile_step()
-        cl.train_step()
-        cl.inject_fail_stop(1, 0)
-        assert cl.detect_and_recover() is not None
-        cl.train_step()
+        work()
     finally:
         jax.profiler.stop_trace()
     path = sorted(glob.glob(str(d / "**" / "*.xplane.pb"), recursive=True))[-1]
@@ -68,7 +61,23 @@ def traced(tmp_path_factory):
                        "start": e.start_ns, "end": e.end_ns,
                        "stats": dict(e.stats)}
                       for e in line.events if e.name.startswith("repro.")]
-    return cl, sorted(spans, key=lambda s: (s["start"], -s["end"]))
+    return sorted(spans, key=lambda s: (s["start"], -s["end"]))
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """(cluster, spans) of a compile, a step, a fail-stop, its recovery
+    and a step."""
+    cl = VirtualCluster(R.tiny_config("dense", num_layers=4), dp=2, pp=2,
+                        global_batch=4, num_micro=1, seq_len=16, seed=0)
+
+    def work():
+        cl.compile_step()
+        cl.train_step()
+        cl.inject_fail_stop(1, 0)
+        assert cl.detect_and_recover() is not None
+        cl.train_step()
+    return cl, _trace(tmp_path_factory.mktemp("trace"), work)
 
 
 def _inside(s, t):
@@ -101,11 +110,12 @@ def test_the_steps_and_their_counts(traced):
             if _inside(s, step):
                 kids.setdefault(s["name"], []).append(s["stats"])
         assert kids["repro.step.device"] == [{"items": items}]
-        # per item, the flat gradient and the loss, in float32
+        # the items' weighted sum, one flat gradient, and a loss per item,
+        # in float32; one bucket, so the host adds nothing
         assert kids["repro.step.fetch"] == [
-            {"nbytes": items * n_params * 4 + items * 4}]
+            {"nbytes": n_params * 4 + items * 4}]
         assert kids["repro.step.accumulate"] == [
-            {"elements": items * n_params}]
+            {"elements": 0, "device_items": items}]
         assert kids["repro.step.writeback"] == [{"nbytes": n_params * 4}]
         assert kids["repro.step.inputs"][0]["nbytes"] > 0
         adam = kids["repro.step.adam"]
@@ -119,6 +129,26 @@ def test_the_steps_and_their_counts(traced):
         # master, mu and nu hashed
         assert sum(s["nbytes"] for s in kids["repro.snapshot.crc"]) \
             == n_params * 12
+
+
+def test_uneven_buckets_add_their_partials_on_the_host(tmp_path):
+    """Sizes [3,3,2] after a fail-stop: two buckets, each weighted and
+    summed in its program and fetched as one flat partial; the host adds
+    the second partial to the first."""
+    cl = VirtualCluster(R.tiny_config("dense", num_layers=4), dp=4, pp=2,
+                        global_batch=16, num_micro=2, seq_len=16, seed=0)
+    cl.recover_fail_stop(1, 1)
+    assert cl.per_rank_mbs == [3, 3, 2]
+    spans = _trace(tmp_path, cl.train_step)
+    n_params = sum(st.total for st in cl.stages)
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s["stats"])
+    assert by["repro.step.device"] == [{"items": 4}, {"items": 2}]
+    assert by["repro.step.fetch"] == [{"nbytes": n_params * 4 + 4 * 4},
+                                      {"nbytes": n_params * 4 + 2 * 4}]
+    assert by["repro.step.accumulate"] == [
+        {"elements": n_params, "device_items": 6}]
 
 
 def test_adam_spans_carry_their_split(traced):
