@@ -3,9 +3,7 @@
 Runs the shared comparison corpus (``repro.kernels.check``) in interpret mode
 on CPU, times both sides best-of-reps, and **exits nonzero** if any case
 exceeds its declared tier in ``repro.kernels.ops.TOLERANCE_TIERS`` — this is
-the CI gate for the kernel layer.  ``benchmarks.train_step_perf`` embeds the
-same rows into ``BENCH_train_step.json`` so the perf artifact carries the
-numerics evidence alongside the wall-clock numbers.
+the CI gate for the kernel layer.
 
 Interpret-mode timings measure the Pallas *interpreter* on CPU, not TPU
 kernel performance; they are trajectory data (is interpret overhead stable

@@ -13,7 +13,7 @@ def main() -> None:
                    convergence_consistency, failslow, kernel_ref,
                    lse_breakdown, migration_mttr, moe_case, proactive_mttr,
                    roofline, scenarios_suite, serve_bench, snapshot_overhead,
-                   spot_trace, throughput_failstop, train_step_perf)
+                   spot_trace, throughput_failstop)
     print("name,us_per_call,derived")
     mods = [
         ("fig11", throughput_failstop),
@@ -28,7 +28,6 @@ def main() -> None:
         ("roofline", roofline),
         ("kernel_ref", kernel_ref),
         ("scenarios", scenarios_suite),
-        ("bench_step", train_step_perf),
         ("bench_serve", serve_bench),
         ("analytic_scale", analytic_scale),
         ("proactive", proactive_mttr),
@@ -37,7 +36,7 @@ def main() -> None:
     for name, mod in mods:
         try:
             rc = mod.main()
-            # gate-style benchmarks (kernel_ref, train_step_perf) return a
+            # gate-style benchmarks (kernel_ref) return a
             # nonzero violation count instead of raising
             if isinstance(rc, int) and rc:
                 failed.append(name)

@@ -24,20 +24,11 @@ elastic run's loss trajectory can be compared against a fault-free run.  The
 distribution layer (who owns what, what moves on which event, what it costs)
 is exactly the paper's; see DESIGN.md §3.
 
-Two step/recovery implementations share this state:
-
-* the **fast path** (default) — one jitted, ``vmap``-batched call over the
-  step's micro-batches that weights and sums their gradients on the device,
-  with a single ``device_get`` of the sum, one fused host-side
-  Adam update per stage, indexed-scatter parameter write-back, and batched
-  recovery that only rebuilds the stages an event actually touches;
-* the **seed path** (``fast_path=False``, ``core/legacy.py``) — the original
-  per-item / per-shard / per-entry loops, kept as the numerics oracle and
-  benchmark baseline.  ``tests/test_fast_path_numerics.py`` asserts the two
-  agree through fail-stop + scale-out events within the declared tolerance
-  of ``core.invariants.ParameterConsistencyChecker``: the two are different
-  XLA programs, which reorder float32 reductions, so they are not bit
-  identical under jax 0.9.0 (nor on a TPU, which tiles them differently).
+The step is one jitted, ``vmap``-batched call per micro-batch size bucket
+that weights and sums the items' gradients on the device, with a single
+``device_get`` of the sum, one fused host-side Adam update per stage, and an
+indexed-scatter parameter write-back.  Recovery rebuilds only the stages an
+event touches, and asserts that every move preserves the stage's state.
 """
 from __future__ import annotations
 
@@ -54,7 +45,6 @@ from repro.models.config import ModelConfig
 from repro.models.layers import RngCtx
 from repro.optim.adam import AdamConfig, adam_plan, adam_update_flat_np
 from repro.spans import span
-from . import legacy
 from .agent import Agent, Probe
 from .clusterview import GroupDelta
 from .controller import ElasticController
@@ -99,17 +89,13 @@ class VirtualCluster:
                  mem_cap: Optional[float] = None,
                  snapshot_enabled: bool = True,
                  non_blocking_migration: bool = True,
-                 fast_path: bool = True,
                  use_pallas: Optional[bool] = None):
         assert global_batch % num_micro == 0
         assert (global_batch // num_micro) % dp == 0, "initial even split"
         if use_pallas is None:
-            # env knob mirrors the fast_path/legacy pattern: default off keeps
-            # the plain-jnp path bit-identical; REPRO_USE_PALLAS=1 routes the
-            # forward through the Pallas kernels (tolerance-tier numerics,
-            # see core/invariants.KernelConsistencyChecker)
-            import os
-            use_pallas = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
+            # the platform picks the kernels: Pallas (Mosaic) on a TPU, the
+            # plain-jnp forward elsewhere; an explicit argument wins
+            use_pallas = jax.default_backend() == "tpu"
         self.use_pallas = bool(use_pallas)
         self.cfg = cfg
         self.dp0, self.pp = dp, pp
@@ -120,7 +106,6 @@ class VirtualCluster:
         self.zero_layout = zero_layout
         self.snapshot_enabled = snapshot_enabled
         self.non_blocking_migration = non_blocking_migration
-        self.fast_path = fast_path
         self.sampler = GlobalBatchSampler(global_batch, seed)
         self.base_key = jax.random.key(seed)
 
@@ -157,7 +142,7 @@ class VirtualCluster:
         for p in range(pp):
             st = self._build_stage_state(p, list(range(dp)))
             self.stages.append(st)
-            pool = SnapshotPool(dp, self.adam, batched=fast_path)
+            pool = SnapshotPool(dp, self.adam)
             if snapshot_enabled:
                 pool.bootstrap(0, [st.shard(r) for r in st.dp_ranks])
             self.snapshots.append(pool)
@@ -181,7 +166,6 @@ class VirtualCluster:
         self.recoveries: List[Dict[str, float]] = []
         self.warnings: List[ElasticEvent] = []   # advisory (OOM_RISK) events
         self.seg = SegmentCosts.build(cfg, seq_len, self.hw)
-        self._grad_fn_cache: Dict[int, Any] = {}
         self._scan_grad_cache: Dict[Tuple[int, int], Any] = {}
 
     # ------------------------------------------------------------------
@@ -214,15 +198,7 @@ class VirtualCluster:
             {"master": full, "mu": np.zeros_like(full),
              "nu": np.zeros_like(full)})
 
-    def _stage_full_vec(self, st: StageState, comp: str = "master") -> np.ndarray:
-        """All-gather equivalent: reassemble the stage's full state vector."""
-        if self.fast_path:
-            return st.full(comp)
-        return legacy.stage_full_vec(st, comp)
-
     def _write_params_from_masters(self):
-        if not self.fast_path:
-            return legacy.write_params_from_masters(self)
         # indexed scatter (one fancy-index per stage, straight into the
         # model-flat buffer) + ONE jitted model unravel (a single
         # host->device transfer for the whole model)
@@ -240,9 +216,7 @@ class VirtualCluster:
     # training math
     # ------------------------------------------------------------------
     def _loss_fn(self, stem, layers, head, tokens, labels, step_key, sample_ids):
-        # self.use_pallas routes the forward through the Pallas kernels; the
-        # legacy path shares this function via _grad_fn, so a fast/legacy twin
-        # pair stays bit-identical in either kernel mode
+        # self.use_pallas routes the forward through the Pallas kernels
         cfg = self.cfg
         x = R.apply_stem(stem, cfg, tokens, use_pallas=self.use_pallas)
         B, S, _ = x.shape
@@ -258,23 +232,15 @@ class VirtualCluster:
         from repro.models.transformer import softmax_xent
         return softmax_xent(logits[:, :-1], labels[:, 1:]) + aux_total
 
-    def _grad_fn(self, batch_size: int):
-        if batch_size not in self._grad_fn_cache:
-            self._grad_fn_cache[batch_size] = jax.jit(
-                jax.value_and_grad(self._loss_fn, argnums=(0, 1, 2)))
-        return self._grad_fn_cache[batch_size]
-
     def _batched_grad_fn(self, batch_size: int, n_items: int):
         """One jitted call over ``n_items`` stacked micro-batches of
         ``batch_size``: the per-item losses, and the items' gradients
         weighted by ``weights`` (f32[n_items]) and summed into one flat
         float32 gradient, so the host fetches one vector however many items
         the bucket holds.  ``vmap`` batches the independent per-item grads
-        (a different XLA program from the per-item jit calls, so it agrees
-        with them to float32 round-off, not bit for bit; a ``lax.scan`` over
-        items is ~1.5x slower on CPU).  The weights are an argument, not a
-        traced constant, so a recovery that reweights the ranks runs the
-        compiled program it has."""
+        (a ``lax.scan`` over items is ~1.5x slower on CPU).  The weights are
+        an argument, not a traced constant, so a recovery that reweights the
+        ranks runs the compiled program it has."""
         key = (batch_size, n_items)
         fn = self._scan_grad_cache.get(key)
         if fn is None:
@@ -307,15 +273,15 @@ class VirtualCluster:
         return fn
 
     def _grad_calls(self, step: int):
-        """The step's micro-items ``(rank, sample ids)`` in the seed's
-        (micro, rank) order, one ``(item indices, jitted fn, args)`` call
-        per micro-batch size bucket (uneven after a failure), and the bytes
-        of tokens, sample ids and item weights uploaded for them.  Each
+        """The step's micro-items ``(rank, sample ids)`` in (micro, rank)
+        order, one ``(item indices, jitted fn, args)`` call per micro-batch
+        size bucket (uneven after a failure), and the bytes of tokens,
+        sample ids and item weights uploaded for them.  Each
         item's weight, ``grad_weights[rank] / num_micro``, is read here on
         every call."""
         ids_by_rank = self.sampler.partition(step, self.per_rank_mbs,
                                              self.num_micro)
-        items: List[Tuple[int, np.ndarray]] = []    # (rank, ids), seed order
+        items: List[Tuple[int, np.ndarray]] = []    # (rank, ids)
         for m in range(self.num_micro):
             for r, rank_ids in enumerate(ids_by_rank):
                 ids = rank_ids[m]
@@ -352,7 +318,7 @@ class VirtualCluster:
         """Weighted accumulation over micro-batches and DP slices — the
         numerics of dataflow-resized hybrid-parallel training.
 
-        Fast path: micro-batches are bucketed by size (uneven after a
+        Micro-batches are bucketed by size (uneven after a
         failure), each bucket runs as ONE jitted vmap-batched call that
         weights and sums its items' gradients on the device, and one
         ``device_get`` per bucket (one per step in the common even-split
@@ -388,7 +354,7 @@ class VirtualCluster:
         return total_loss, acc
 
     def compile_step(self) -> List[Any]:
-        """Compile the fast path's grad programs for the next ``train_step``
+        """Compile the grad programs for the next ``train_step``
         (one per micro-batch size bucket) ahead of it and return them; the
         step then finds them in the jit cache.  Lets a caller time the
         compile apart from the step and read the compiled program."""
@@ -402,8 +368,6 @@ class VirtualCluster:
         return programs
 
     def train_step(self) -> float:
-        if not self.fast_path:
-            return legacy.train_step(self)
         step = self.step_count
         with span("step", step=step):
             loss, gflat = self._micro_grads(step)
@@ -422,9 +386,9 @@ class VirtualCluster:
                     grad_shard_by_stage.append(tbl.split(gshard))
                     if st.total:
                         # ONE fused host-side Adam update over the stage's
-                        # flat buffers (bit-identical to the seed's per-shard
-                        # eager updates); the per-rank shards are views into
-                        # the result
+                        # flat buffers (elementwise, so the shard order does
+                        # not change a bit); the per-rank shards are views
+                        # into the result
                         st.flat = adam_update_flat_np(gshard, st.flat,
                                                       self.opt_step, self.adam)
             self._write_params_from_masters()
@@ -669,7 +633,7 @@ class VirtualCluster:
           with zeroed Adam moments (``rebuilt``, counted as degraded).
 
         Repairs land in ``pool.host`` *before* ``_live_remap_stage`` reads
-        it, so both the fast and the legacy remap paths stay untouched.
+        it, so the remap itself stays untouched.
         Returns (modeled verify seconds, degraded-shard count).
         """
         if not self.snapshot_enabled:
@@ -732,8 +696,6 @@ class VirtualCluster:
     def _widen_stage(self, p: int, joining: List[int]) -> float:
         """Reverse remap: redistribute the stage state over a WIDER group.
         Sources: current owners' device shards; targets: new layout."""
-        if not self.fast_path:
-            return legacy.widen_stage(self, p, joining)
         st = self.stages[p]
         old_ranks = list(st.dp_ranks)
         tbl = st.table
@@ -759,8 +721,7 @@ class VirtualCluster:
         for comp in COMPONENTS:
             assert np.array_equal(st.full(comp), pre[comp]), \
                 f"widen corrupted {comp}"
-        self.snapshots[p] = SnapshotPool(len(new_ranks), self.adam,
-                                         batched=True)
+        self.snapshots[p] = SnapshotPool(len(new_ranks), self.adam)
         if self.snapshot_enabled:
             self.snapshots[p].bootstrap(self.step_count,
                                         [st.shard(r) for r in new_ranks])
@@ -823,8 +784,6 @@ class VirtualCluster:
 
     def _live_remap_stage(self, p: int, failed: List[int],
                           ) -> Tuple[float, RemapPlan]:
-        if not self.fast_path:
-            return legacy.live_remap_stage(self, p, failed)
         st = self.stages[p]
         pool = self.snapshots[p]
         tbl = st.table
@@ -873,8 +832,7 @@ class VirtualCluster:
             assert np.array_equal(st.full(comp), pre[comp]), \
                 f"remap corrupted {comp}"
         # rebuild ring snapshot pool for the shrunken group
-        self.snapshots[p] = SnapshotPool(len(surviving), self.adam,
-                                         batched=True)
+        self.snapshots[p] = SnapshotPool(len(surviving), self.adam)
         if self.snapshot_enabled:
             self.snapshots[p].bootstrap(self.step_count,
                                         [st.shard(r) for r in surviving])
@@ -884,8 +842,6 @@ class VirtualCluster:
                                        failed: List[int]) -> np.ndarray:
         """Pre-failure ground truth: survivors' device state + failed ranks'
         snapshot state."""
-        if not self.fast_path:
-            return legacy.stage_full_vec_with_snapshots(self, p, comp, failed)
         st = self.stages[p]
         pool = self.snapshots[p]
         tbl = st.table
@@ -906,12 +862,10 @@ class VirtualCluster:
         """Move layers between stages: optimizer-state slices (per layout) +
         parameter ownership.  Returns modeled stall seconds (MTTR).
 
-        Fast path: only the stages whose entry list actually changes are
+        Only the stages whose entry list actually changes are
         rebuilt (a slice-move between two stages leaves the others' flat
         buffers and snapshot pools untouched); entry slices come from one
         gather per component per affected stage."""
-        if not self.fast_path:
-            return legacy.apply_migrations(self, moves, new_ranges)
         with span("recover.migration") as sp:
             total_stall = 0.0
             # compute per-move timing with the migration model
@@ -958,16 +912,13 @@ class VirtualCluster:
                                               self.zero_layout, survivors,
                                               full_by_comp)
                 self.stages[p] = new_st
-                self.snapshots[p] = SnapshotPool(len(survivors), self.adam,
-                                                 batched=True)
+                self.snapshots[p] = SnapshotPool(len(survivors), self.adam)
                 if self.snapshot_enabled:
                     self.snapshots[p].bootstrap(
                         self.step_count, [new_st.shard(r) for r in survivors])
         return total_stall
 
     def _entry_from_stage(self, e: int) -> Dict[str, np.ndarray]:
-        if not self.fast_path:
-            return legacy.entry_from_stage(self, e)
         for st in self.stages:
             if e in st.entries:
                 pos = st.entries.index(e)

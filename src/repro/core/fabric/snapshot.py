@@ -12,11 +12,11 @@ each step O_i^host == O_{(i+1)%n}^device bit-for-bit — which Live Remap
 relies on for integrity.  The host work is timed by the program spans
 ``repro.snapshot.adam`` and ``repro.snapshot.crc`` (``repro/spans.py``).
 
-The default (batched) fast path concatenates every rank's gradient shard and
-host state into one flat vector per component and runs ONE host Adam update
-(and, under ``compress="bf16"``, one compression round-trip) for the whole DP
-group — elementwise identical to the seed per-rank loop, which is preserved
-under ``batched=False`` as the benchmark baseline.
+Every rank's gradient shard and host state are concatenated into one flat
+vector per component, so ONE host Adam update (and, under
+``compress="bf16"``, one compression round-trip) covers the whole DP group;
+the update is elementwise, so each rank's slice gets the bits a per-rank
+update would give.
 """
 from __future__ import annotations
 
@@ -27,8 +27,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.optim.adam import (AdamConfig, adam_plan, adam_update_flat,
-                              adam_update_flat_np)
+from repro.optim.adam import AdamConfig, adam_plan, adam_update_flat_np
 from repro.spans import span
 
 from ..statespace import COMPONENTS as _COMPONENTS
@@ -58,18 +57,16 @@ class SnapshotPool:
     integrity goal is optimizer-semantics preservation, which holds)."""
 
     def __init__(self, n: int, adam_cfg: Optional[AdamConfig] = None,
-                 compress: str = "none", batched: bool = True,
-                 integrity: bool = True):
+                 compress: str = "none", integrity: bool = True):
         self.n = n
         self.adam = adam_cfg or AdamConfig()
         assert compress in ("none", "bf16")
         self.compress = compress
-        self.batched = batched
         self.integrity = integrity
-        # host[i] = snapshot of worker (i+1) % n's shard state.  On the
-        # batched path these are zero-copy views into one concatenated
-        # buffer per component (_cat), so the per-step host Adam update is
-        # ONE vectorized call with no per-rank splitting.
+        # host[i] = snapshot of worker (i+1) % n's shard state: zero-copy
+        # views into one concatenated buffer per component (_cat), so the
+        # per-step host Adam update is ONE vectorized call with no per-rank
+        # splitting.
         self.host: List[Optional[Dict[str, np.ndarray]]] = [None] * n
         self.snap_step: List[int] = [-1] * n
         self._cat: Optional[Dict[str, np.ndarray]] = None
@@ -98,8 +95,8 @@ class SnapshotPool:
         self._stamp_all()
 
     def _ensure_cat(self):
-        """Build (lazily) the concatenated per-component buffers the batched
-        path updates in one shot; host[i] become views into them."""
+        """Build (lazily) the concatenated per-component buffers the step
+        updates in one shot; host[i] become views into them."""
         if self._cat is not None:
             return
         for st in self.host:
@@ -122,10 +119,8 @@ class SnapshotPool:
 
         grad_shards[j]: fp32 gradient of worker j's owned shard (1-D).
         """
-        if not self.batched:
-            return self._snapshot_step_loop(step, grad_shards, opt_step)
-        # batched fast path: one concatenated compression + host-Adam update
-        # covering every holder's snapshot (elementwise == the per-rank loop)
+        # one concatenated compression + host-Adam update covering every
+        # holder's snapshot
         with span("snapshot.adam") as sp:
             self._ensure_cat()
             gs = [np.asarray(grad_shards[self.backup_rank(i)],
@@ -148,32 +143,6 @@ class SnapshotPool:
                   nbytes=sum(v.nbytes for v in self._cat.values())
                   if self.integrity else 0):
             self._stamp_all()
-        return SnapshotStats(
-            step=step,
-            grad_bytes_sent=total_grad_bytes,
-            state_bytes_equiv=total_grad_bytes // GRAD_BYTES * ADAM_STATE_BYTES)
-
-    def _snapshot_step_loop(self, step: int, grad_shards: List[np.ndarray],
-                            opt_step: int) -> SnapshotStats:
-        """Seed per-rank loop (benchmark baseline; imports hoisted)."""
-        total_grad_bytes = 0
-        for i in range(self.n):
-            j = self.backup_rank(i)
-            g = np.asarray(grad_shards[j], dtype=np.float32)
-            if self.compress == "bf16":
-                g = np.asarray(jnp.asarray(g).astype(jnp.bfloat16)
-                               .astype(jnp.float32))
-                total_grad_bytes += g.size * 2        # bf16 on the wire
-            else:
-                total_grad_bytes += g.nbytes
-            st = self.host[i]
-            assert st is not None, "bootstrap() first"
-            new_master, new_st = adam_update_flat(
-                jnp.asarray(g), {k: jnp.asarray(v) for k, v in st.items()},
-                opt_step, self.adam)
-            self.host[i] = {k: np.asarray(v) for k, v in new_st.items()}
-            self.snap_step[i] = step
-        self._stamp_all()
         return SnapshotStats(
             step=step,
             grad_bytes_sent=total_grad_bytes,
@@ -220,8 +189,8 @@ class SnapshotPool:
         i = index % arr.size
         raw = arr[i:i + 1].view(np.uint32)
         raw ^= np.uint32(0x00400000)   # flip a mantissa bit
-        # (mutates in place; on the batched path this writes through the
-        # _cat view, exactly like real bit rot in the holder's host buffer)
+        # (mutates in place; this writes through the _cat view, exactly
+        # like real bit rot in the holder's host buffer)
 
     def verify_shard(self, j: int) -> bool:
         """Re-hash worker j's stored snapshot against its write-time
@@ -263,7 +232,7 @@ class SnapshotPool:
           unrecoverable.
 
         Repairs write standalone arrays into the holder slot (detaching it
-        from any batched ``_cat`` buffer) and refresh the checksum.
+        from the shared ``_cat`` buffer) and refresh the checksum.
         """
         h = self.holder_of(j)
         st = self.host[h]

@@ -5,8 +5,7 @@ link-code arrays + memoized group index tables).  This module keeps the seed
 implementation — Python dicts of member lists, a ``set`` of ``frozenset``
 links — so property tests can enforce, at dp×pp×tp ≤ 64 ranks, that the
 vectorized ``apply(delta, policy)`` produces byte-identical ``OpStats``,
-group tables, link sets, ``affected_groups`` ordering and MTTR accounting
-(mirroring the PR 2 fast-path/``core.legacy`` pattern).
+group tables, link sets, ``affected_groups`` ordering and MTTR accounting.
 
 One intentional delta from the seed: affected groups are processed in
 ``sorted(...)`` name order instead of Python ``set`` iteration order, in both

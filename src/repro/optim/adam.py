@@ -5,8 +5,9 @@ The state pytree mirrors the param pytree, so the FSDP/ZeRO sharding rules in
 parallel/sharding.py apply verbatim (this is ZeRO-3 semantics under pjit: XLA
 all-gathers weights for compute, reduce-scatters grads back to the shards).
 
-The ElasWave VirtualCluster uses the same math through `adam_update_flat` on
-flattened per-layer vectors (its ZeRO-1 shards).
+The ElasWave VirtualCluster uses the same math on flattened per-layer vectors
+(its ZeRO-1 shards) through `adam_update_flat_np`, the host mirror of
+`adam_update_flat`.
 """
 from __future__ import annotations
 
@@ -160,7 +161,7 @@ def adam_update_flat_np(grad_vec, st, step: int, cfg: AdamConfig, out=None):
     f32 produces identical bits — while avoiding the ~8 per-call dispatches
     and host<->device round-trips of the eager path.  (A *jitted* fused
     version is NOT equivalent: XLA contracts mul+add chains into FMAs.)
-    Used by the VirtualCluster fast path and the batched SnapshotPool;
+    Used by the VirtualCluster step and the SnapshotPool;
     bit-identity to the eager path is checked by
     ``tests/test_zero_and_fabric.py``.
 

@@ -367,7 +367,7 @@ def make_pallas_case(seed: int) -> FuzzCase:
     """Pallas-mode cluster fuzzing: same trace grammar with the kernels in
     the training hot path.  ``run_case`` sees ``workload.use_pallas`` and
     swaps in the tolerance-tier :class:`KernelConsistencyChecker` for the
-    fast/legacy parameter twin.  Interpret-mode kernels are slow, so traces
+    ZeRO-layout parameter twin.  Interpret-mode kernels are slow, so traces
     are shorter than plain cluster mode."""
     rnd = random.Random(f"pallas-{seed}")
     w = dataclasses.replace(draw_cluster_workload(rnd),

@@ -49,10 +49,10 @@ class ClusterWorkload:
 
     def make_cluster(self, **overrides):
         """Build the VirtualCluster.  ``overrides`` pass straight through to
-        the constructor — e.g. ``fast_path=False`` builds the
-        ``core/legacy.py`` twin the invariant harness locksteps against, and
-        ``use_pallas=False`` builds the plain-jnp twin the tolerance-tier
-        kernel checker compares a pallas-mode run against."""
+        the constructor — e.g. ``zero_layout="contiguous"`` builds the
+        twin the parameter-consistency checker locksteps against bit for
+        bit, and ``use_pallas=False`` builds the plain-jnp twin the
+        tolerance-tier kernel checker compares a pallas-mode run against."""
         from repro.core.cluster import VirtualCluster
         from repro.models import registry as R
         cfg = R.tiny_config(self.family, num_layers=self.num_layers,

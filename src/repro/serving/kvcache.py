@@ -11,7 +11,7 @@ Gather/scatter follow the flat-state backbone's idiom
 loops.  A migration between replicas is ``gather_slots`` on the source pool +
 ``scatter_slots`` into the destination pool — a pure array copy, so migrated
 decode streams are bit-identical to undisturbed ones (the serving analogue of
-the training fast path's zero-copy shard views being bit-exact).
+the training step's zero-copy shard views being bit-exact).
 """
 from __future__ import annotations
 

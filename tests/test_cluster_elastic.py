@@ -90,7 +90,7 @@ class TestParameterConsistency:
         # _live_remap_stage before migration reshuffles the stage spaces.)
         st_new = el.stages[1]
         assert 1 not in st_new.dp_ranks
-        full = el._stage_full_vec(st_new)
+        full = st_new.full("master")
         assert full.size == st_new.total
 
 

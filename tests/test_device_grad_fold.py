@@ -1,4 +1,4 @@
-"""The fast path weights and sums the micro-items' gradients inside its grad
+"""The elastic step weights and sums the micro-items' gradients inside its grad
 program (``VirtualCluster._batched_grad_fn``); the host only adds the
 partial sums of several micro-batch size buckets.
 
@@ -43,8 +43,9 @@ CASES = {"one_bucket": (one_bucket, 1), "uneven_3_3_2": (uneven, 2)}
 def per_item(cl):
     """``(rank, loss, flat gradient)`` of every item of the next step, in
     the seed's order, read from the step's own programs; each also checked
-    against the item's own per-item jit (``_grad_fn``, a different XLA
-    program, so to round-off), which a fold that mixed the items fails."""
+    against the item's own per-item jit (a different XLA program, so to
+    round-off), which a fold that mixed the items fails."""
+    grad_one = jax.jit(jax.value_and_grad(cl._loss_fn, argnums=(0, 1, 2)))
     items, calls, _ = cl._grad_calls(cl.step_count)
     out = [None] * len(items)
     for idxs, fn, args in calls:
@@ -55,8 +56,8 @@ def per_item(cl):
             onehot[j] = 1.0
             losses, g = fn(*args[:-1], onehot)
             g = np.asarray(g)
-            _, ref = cl._grad_fn(len(items[k][1]))(
-                stem, layers, head, toks[j], toks[j], step_key, sids[j])
+            _, ref = grad_one(stem, layers, head, toks[j], toks[j], step_key,
+                              sids[j])
             ref = np.asarray(ravel_pytree(ref)[0])
             assert np.linalg.norm(g - ref) <= 1e-5 * np.linalg.norm(ref)
             out[k] = (items[k][0], float(losses[j]), g)

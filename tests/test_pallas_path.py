@@ -1,8 +1,8 @@
 """Pallas kernels in the training hot path: flag threading, per-family
-pallas-vs-jnp forward-loss tolerance, the REPRO_USE_PALLAS knob, and the
-tolerance-tier invariant stack over an elastic scenario (fail-stop +
-scale-out).  Tiny configs throughout; interpret-mode numeric cases beyond the
-dense smoke are marked ``slow``."""
+pallas-vs-jnp forward-loss tolerance, the platform's default for the
+kernels, and the tolerance-tier invariant stack over an elastic scenario
+(fail-stop + scale-out).  Tiny configs throughout; interpret-mode numeric
+cases beyond the dense smoke are marked ``slow``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -100,18 +100,15 @@ CLUSTER_KW = dict(dp=2, pp=1, global_batch=2, num_micro=1, seq_len=8, seed=0)
 
 
 class TestUsePallasKnob:
-    def test_env_and_arg_resolution(self, monkeypatch):
+    def test_env_and_arg_resolution(self):
+        """``None`` takes the platform's answer (the kernels on a TPU, the
+        plain-jnp forward elsewhere); an explicit argument wins."""
         cfg = R.tiny_config("dense", num_layers=2)
-        monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
-        assert VirtualCluster(cfg, **CLUSTER_KW).use_pallas is False
-        monkeypatch.setenv("REPRO_USE_PALLAS", "1")
-        assert VirtualCluster(cfg, **CLUSTER_KW).use_pallas is True
-        monkeypatch.setenv("REPRO_USE_PALLAS", "0")
-        assert VirtualCluster(cfg, **CLUSTER_KW).use_pallas is False
-        # explicit argument beats the environment
-        monkeypatch.setenv("REPRO_USE_PALLAS", "1")
-        assert VirtualCluster(cfg, use_pallas=False,
-                              **CLUSTER_KW).use_pallas is False
+        on_tpu = jax.default_backend() == "tpu"
+        assert VirtualCluster(cfg, **CLUSTER_KW).use_pallas is on_tpu
+        for explicit in (False, True):
+            assert VirtualCluster(cfg, use_pallas=explicit,
+                                  **CLUSTER_KW).use_pallas is explicit
 
     def test_workload_field_reaches_cluster(self):
         w = ClusterWorkload(dp=2, pp=1, global_batch=2, num_micro=1,
